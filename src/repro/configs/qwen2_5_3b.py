@@ -1,14 +1,14 @@
-"""qwen2.5-3b [dense] — hf:Qwen/Qwen2.5-0.5B family (3B scale).
+"""qwen2.5-3b [dense] — hf:Qwen/Qwen2.5-3B (config.json).
 
 36 layers, d_model=2048, 16 heads (GQA kv=2), d_ff=11008,
-vocab=151936, QKV bias.
+vocab=151936, QKV bias, tied input/output embeddings.
 """
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
     arch_id="qwen2.5-3b",
     family="dense",
-    source="hf:Qwen/Qwen2.5-0.5B",
+    source="hf:Qwen/Qwen2.5-3B",
     num_layers=36,
     d_model=2048,
     num_heads=16,
@@ -16,6 +16,7 @@ CONFIG = ModelConfig(
     d_ff=11008,
     vocab_size=151936,
     qkv_bias=True,
+    tie_embeddings=True,
     rope_theta=1000000.0,
     param_dtype="bfloat16",
     compute_dtype="bfloat16",

@@ -20,7 +20,6 @@ from typing import Any, Callable, Iterator, Optional
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -60,8 +59,8 @@ def shard_over_data(fn: Callable, mesh: Mesh, axes: tuple,
         n_rep = len(args) - 1
         in_specs = (P(),) * n_rep \
             + (batch_axes_pspec(axes, accum_steps),)
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(), check_rep=False)(*args)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(), check_vma=False)(*args)
     return wrapped
 
 

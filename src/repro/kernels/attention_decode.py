@@ -129,7 +129,7 @@ def _decode_kernel(pos_ref, q_ref, nk_ref, nv_ref, kc_ref, vc_ref,
 
 def attention_decode_pallas(q, new_k, new_v, k_cache, v_cache, pos, *,
                             window: Optional[int] = None,
-                            interpret: bool = True):
+                            interpret: bool):
     """Fused decode attention. q: [B,1,H,Dh] (rope'd); new_k/new_v:
     [B,1,Hkv,Dh] (rope'd); caches: [B,T,Hkv,Dh]; pos: [B] int32
     per-row depths. Returns (out [B,1,H,Dh], new_k_cache, new_v_cache)

@@ -109,7 +109,7 @@ def _norms_sq(w2d: jnp.ndarray, g2d: jnp.ndarray, *, interpret: bool
 def lars_update_pallas(w: jnp.ndarray, g: jnp.ndarray, m: jnp.ndarray, *,
                        base_lr, eta: float, weight_decay: float,
                        momentum_mu: float, eps: float = 1e-9,
-                       nesterov: bool = False, interpret: bool = True):
+                       nesterov: bool = False, interpret: bool):
     """Fused LARS step. Returns (new_momentum, delta), f32, shape of w."""
     orig_shape = w.shape
     w2d, n = _pad_to_tiles(w.astype(jnp.float32))

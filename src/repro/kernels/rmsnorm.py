@@ -30,7 +30,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_pallas(x: jnp.ndarray, weight: jnp.ndarray, *,
-                   eps: float = 1e-6, interpret: bool = True) -> jnp.ndarray:
+                   eps: float = 1e-6, interpret: bool) -> jnp.ndarray:
     """x: (..., d), weight: (d,). Returns same shape/dtype as x."""
     orig_shape = x.shape
     d = orig_shape[-1]

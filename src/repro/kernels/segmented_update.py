@@ -69,6 +69,15 @@ def _onehot(ids_block: jnp.ndarray, nseg_pad: int) -> jnp.ndarray:
     return (ids_block == cols).astype(jnp.float32)
 
 
+def _onehot_dot(a, b):
+    """f32 contraction against a one-hot operand. HIGHEST pins the MXU
+    to full f32: the scatter/gather must be exact, and a single bf16
+    pass would round every row's partial sum (and every gathered
+    trust scale) to 8 mantissa bits."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
 def _store_state(val32, out_ref, buf: int, *, sr: bool, seed_ref,
                  block_rows: int) -> None:
     """Write an f32 state tile back at the buffer's storage dtype —
@@ -97,7 +106,7 @@ def _seg_norm_lars(ids_ref, w_ref, g_ref, out_ref, *, nseg_pad: int):
     g = g_ref[...].astype(jnp.float32)
     oh = _onehot(ids_ref[...], nseg_pad)
     rows = jnp.stack([jnp.sum(w * w, axis=1), jnp.sum(g * g, axis=1)])
-    out_ref[...] += jnp.dot(rows, oh, preferred_element_type=jnp.float32)
+    out_ref[...] += _onehot_dot(rows, oh)
 
 
 def _seg_norm_lamb(ids_ref, sc_ref, w_ref, g_ref, mu_ref, nu_ref, out_ref,
@@ -119,7 +128,7 @@ def _seg_norm_lamb(ids_ref, sc_ref, w_ref, g_ref, mu_ref, nu_ref, out_ref,
     b = d + weight_decay * w
     oh = _onehot(ids_ref[...], nseg_pad)
     rows = jnp.stack([jnp.sum(w * w, axis=1), jnp.sum(b * b, axis=1)])
-    out_ref[...] += jnp.dot(rows, oh, preferred_element_type=jnp.float32)
+    out_ref[...] += _onehot_dot(rows, oh)
 
 
 # ---------------------------------------------------------------------------
@@ -129,7 +138,7 @@ def _seg_norm_lamb(ids_ref, sc_ref, w_ref, g_ref, mu_ref, nu_ref, out_ref,
 def _gather_scales(ids_ref, tab_ref, nseg_pad: int):
     """Per-row (sg, sw) via one-hot @ tableᵀ -> two (B, 1) columns."""
     oh = _onehot(ids_ref[...], nseg_pad)
-    sgw = jnp.dot(oh, tab_ref[...].T, preferred_element_type=jnp.float32)
+    sgw = _onehot_dot(oh, tab_ref[...].T)
     return sgw[:, 0:1], sgw[:, 1:2]
 
 
@@ -216,7 +225,7 @@ def segmented_update_pallas(w2d, g2d, bufs, *, seg_ids, adapt_mask, base_lr,
                             trust_clip=None, bc1=1.0, bc2=1.0,
                             stochastic_round: bool = False, seed=0,
                             telemetry: bool = False,
-                            interpret: bool = True):
+                            interpret: bool):
     """Whole-tree layer-wise step: exactly two ``pallas_call``s.
 
     Same contract as ``ref.ref_segmented_update`` — flat ``(rows, 128)``

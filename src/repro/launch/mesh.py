@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _check_devices(shape: tuple[int, ...], axes: tuple[str, ...]) -> None:
@@ -38,17 +38,24 @@ def _check_devices(shape: tuple[int, ...], axes: tuple[str, ...]) -> None:
             f"(set BEFORE the first jax device access) or shrink the mesh")
 
 
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: since JAX 0.9 the default is
+    Explicit, and ``with_sharding_constraint`` (the activation anchors
+    in ``models.layers``) may only name Auto axes."""
+    _check_devices(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    _check_devices(shape, axes)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small mesh over whatever devices exist (tests / CPU runs)."""
-    _check_devices((data, model), ("data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_data_mesh(data: int, model: int = 1) -> Mesh:

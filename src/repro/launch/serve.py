@@ -27,7 +27,7 @@ import numpy as np
 from repro import serving
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.diagnostics import sink as diag_sink
-from repro.launch import sharding
+from repro.launch import compile_cache, sharding
 from repro.launch.mesh import make_host_mesh
 from repro.models import extra_embed_shape, get_model
 from repro.obs import trace as obs_trace
@@ -55,6 +55,7 @@ def main() -> None:
     ap.add_argument("--trace-out", default=None,
                     help="write engine phase spans (trace-v1 JSONL)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = get_model(cfg)

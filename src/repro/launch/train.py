@@ -80,7 +80,7 @@ from repro.data import pipeline
 from repro.data.synthetic import lm_batch, lm_sample_source
 from repro.diagnostics import probes
 from repro.diagnostics import sink as diag_sink
-from repro.launch import sharding
+from repro.launch import compile_cache, sharding
 from repro.launch.mesh import make_host_mesh
 from repro.models import extra_embed_shape, get_model
 from repro.models import layers as layers_lib
@@ -237,6 +237,7 @@ def main() -> None:
     # global per-pass size there, vs per-device under mesh-native)
     mesh_native = args.mesh_data is not None and mesh_model == 1 \
         and mesh_data > 1
+    compile_cache.enable()
 
     global_batch = args.global_batch if args.global_batch is not None \
         else args.batch

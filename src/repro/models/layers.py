@@ -543,14 +543,9 @@ def _vocab_parallel_embed(table: jnp.ndarray, tokens: jnp.ndarray
         x = jnp.where(ok[..., None], x, jnp.zeros((), x.dtype))
         return jax.lax.psum(x, "model")
 
-    # jax.shard_map is only public from jax>=0.5; 0.4.x has it under
-    # jax.experimental (same semantics)
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh,
-                     in_specs=(P("model", None), tok_spec),
-                     out_specs=out_spec)(table, tokens)
+    return jax.shard_map(f, mesh=mesh,
+                         in_specs=(P("model", None), tok_spec),
+                         out_specs=out_spec)(table, tokens)
 
 
 def embed(params: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:

@@ -53,7 +53,6 @@ from typing import Callable, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import apply_updates, instrumentation
@@ -153,8 +152,30 @@ def _sharded_grad_fn(task, mesh: Mesh, axes, accum_steps: int):
                 jax.tree_util.tree_map(pm, grads))
 
     bspec = pipeline.batch_axes_pspec(axes, accum_steps)
-    return shard_map(local, mesh=mesh, in_specs=(P(), bspec),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(), bspec),
+                         out_specs=P(), check_vma=False)
+
+
+def _optimizer_fn(optimizer: GradientTransform, layerwise: bool,
+                  mesh: Optional[Mesh]) -> Callable:
+    """``(grads, opt_state, params) -> (updates, opt_state, telemetry)``.
+
+    ``telemetry`` is what the ``repro.obs.layerwise`` tap caught
+    (empty unless ``layerwise``). With a ``mesh`` every operand is
+    replicated, and the update runs under ``shard_map`` so that each
+    device applies it to its own copy: the TPU compiler cannot
+    partition a Pallas (Mosaic) call, even over replicated operands."""
+    def apply(grads, opt_state, params):
+        if not layerwise:
+            return (*optimizer.update(grads, opt_state, params), {})
+        with obs_layerwise.capture() as tap:
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+        return updates, opt_state, dict(tap)
+
+    if mesh is None:
+        return apply
+    return jax.shard_map(apply, mesh=mesh, in_specs=(P(), P(), P()),
+                         out_specs=P(), check_vma=False)
 
 
 def make_train_step(task: Union[tasks.Task, Model],
@@ -210,6 +231,8 @@ def make_train_step(task: Union[tasks.Task, Model],
         sharded = _sharded_grad_fn(task, mesh, data_axes, accum_steps)
     else:
         sharded = None
+    apply_optimizer = _optimizer_fn(optimizer, layerwise,
+                                    mesh if dp > 1 else None)
 
     def train_step(state: TrainState, *batch_args):
         batch = batch_args[0] if len(batch_args) == 1 else batch_args
@@ -226,13 +249,7 @@ def make_train_step(task: Union[tasks.Task, Model],
             raise ValueError(
                 f"task {task.name!r} metrics {sorted(clash)} collide with "
                 f"trainer-reserved metric names")
-        if layerwise:
-            with obs_layerwise.capture() as tap:
-                updates, opt_state = optimizer.update(
-                    grads, state.opt_state, state.params)
-        else:
-            tap = {}
-            updates, opt_state = optimizer.update(grads, state.opt_state,
+        updates, opt_state, tap = apply_optimizer(grads, state.opt_state,
                                                   state.params)
         params = apply_updates(state.params, updates)
         metrics = {"loss": loss, **task_metrics,

@@ -107,6 +107,8 @@ def test_full_config_matches_assignment(arch_id):
         assert cfg.num_experts == 64 and cfg.experts_per_token == 8
     if arch_id == "gemma3-12b":
         assert cfg.sliding_window == 1024 and cfg.global_every == 6
+    if arch_id == "qwen2.5-3b":
+        assert cfg.source == "hf:Qwen/Qwen2.5-3B" and cfg.tie_embeddings
     if arch_id == "whisper-large-v3":
         assert cfg.encoder_layers == 32 and cfg.encoder_seq == 1500
 

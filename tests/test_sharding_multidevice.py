@@ -25,6 +25,7 @@ from repro.core import build_optimizer
 from repro.training.train_state import TrainState
 from repro.training.trainer import make_train_step
 from repro.launch import sharding
+from repro.launch.mesh import make_host_mesh
 from repro.data.synthetic import lm_batch
 
 assert len(jax.devices()) == 8
@@ -47,7 +48,7 @@ ref_state, ref_metrics = step(state, batch)
 ref_loss = float(ref_metrics["loss"])
 
 # (2, 4) mesh with full production sharding
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(2, 4)
 with mesh:
     layers_lib.set_batch_sharding(("data",), "model", model_size=4,
                                   mesh=mesh)
@@ -108,6 +109,7 @@ from repro.configs.base import ModelConfig
 from repro.models import get_model
 from repro.models import layers as layers_lib
 from repro.launch import sharding
+from repro.launch.mesh import make_host_mesh
 from repro.serving.decode import make_serve_step
 
 cfg = ModelConfig(family="dense", num_layers=2, d_model=64, num_heads=4,
@@ -120,7 +122,7 @@ cache = m.init_cache(params, 8, 16, None)
 serve = make_serve_step(m)
 ref_tok, _ = serve(params, cache, toks, jnp.int32(0))
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(2, 4)
 with mesh:
     layers_lib.set_batch_sharding(("data",), None, model_size=4, mesh=mesh)
     params_sh = sharding.named(
