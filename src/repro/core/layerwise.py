@@ -71,6 +71,7 @@ from repro.core import labels as labels_lib
 from repro.core.base import GradientTransform, PyTree
 from repro.kernels import ref
 from repro.obs import layerwise as obs_layerwise
+from repro.obs import scopes
 
 UseKernel = Union[bool, str]
 
@@ -212,9 +213,11 @@ def layerwise_transform(base_lr_fn: Callable[[jnp.ndarray], jnp.ndarray], *,
         base_lr, bc1, bc2 = _step_scalars(state)
         from repro.kernels import ops as kops
         telemetry = obs_layerwise.active()
+        with jax.named_scope(scopes.PACK):
+            w2d = flatten.pack_tree(params, spec)
+            g2d = flatten.pack_tree(grads, spec)
         out = kops.segmented_update(
-            flatten.pack_tree(params, spec), flatten.pack_tree(grads, spec),
-            tuple(state[1:]),
+            w2d, g2d, tuple(state[1:]),
             seg_ids=spec.segment_ids(), adapt_mask=spec.adapt_mask(),
             base_lr=base_lr, mode=mode, eta=eta,
             weight_decay=weight_decay, momentum=momentum, b1=b1, b2=b2,
@@ -228,7 +231,8 @@ def layerwise_transform(base_lr_fn: Callable[[jnp.ndarray], jnp.ndarray], *,
             obs_layerwise.deposit(telem)
         else:
             new_bufs, delta2d = out
-        updates = flatten.unpack_tree(delta2d, spec)
+        with jax.named_scope(scopes.UNPACK):
+            updates = flatten.unpack_tree(delta2d, spec)
         return updates, state_cls(state.step + 1, *new_bufs)
 
     # ---- tree paths: per-leaf jnp math, optional per-tensor kernel ----

@@ -60,6 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.flatten import LANES, max_block_rows
 from repro.kernels import ref
+from repro.obs import scopes
 
 
 def _onehot(ids_block: jnp.ndarray, nseg_pad: int) -> jnp.ndarray:
@@ -268,21 +269,25 @@ def segmented_update_pallas(w2d, g2d, bufs, *, seg_ids, adapt_mask, base_lr,
         norm_kernel = functools.partial(_seg_norm_lars, nseg_pad=nseg_pad)
         norm_in = [ids_block, block, block]
         norm_args = (seg_ids, w2d, g2d)
-    table2 = pl.pallas_call(
-        norm_kernel,
-        grid=grid,
-        in_specs=norm_in,
-        out_specs=tab_block,
-        out_shape=jax.ShapeDtypeStruct((2, nseg_pad), jnp.float32),
-        interpret=interpret,
-    )(*norm_args)
+    with jax.named_scope(scopes.SEG_NORM):
+        table2 = pl.pallas_call(
+            norm_kernel,
+            grid=grid,
+            in_specs=norm_in,
+            out_specs=tab_block,
+            out_shape=jax.ShapeDtypeStruct((2, nseg_pad), jnp.float32),
+            interpret=interpret,
+        )(*norm_args)
 
     # ---- host: per-segment trust table, padded back to nseg_pad ----
-    wn, bn, ratio = ref.trust_ratio(
-        table2[0, :nseg], table2[1, :nseg], adapt_mask, mode=mode,
-        eta=eta, weight_decay=weight_decay, eps=eps, trust_clip=trust_clip)
-    table = ref.scales_from_ratio(ratio, adapt_mask, base_lr, weight_decay)
-    table = jnp.pad(table, ((0, 0), (0, nseg_pad - nseg)))
+    with jax.named_scope(scopes.TRUST_TABLE):
+        wn, bn, ratio = ref.trust_ratio(
+            table2[0, :nseg], table2[1, :nseg], adapt_mask, mode=mode,
+            eta=eta, weight_decay=weight_decay, eps=eps,
+            trust_clip=trust_clip)
+        table = ref.scales_from_ratio(ratio, adapt_mask, base_lr,
+                                      weight_decay)
+        table = jnp.pad(table, ((0, 0), (0, nseg_pad - nseg)))
 
     # ---- pass 2: gathered-scale elementwise apply ----
     if mode == "lamb":
@@ -302,14 +307,15 @@ def segmented_update_pallas(w2d, g2d, bufs, *, seg_ids, adapt_mask, base_lr,
     # state buffers keep their storage dtype; the delta is always f32
     out_shape = [jax.ShapeDtypeStruct(b.shape, b.dtype) for b in bufs] \
         + [jax.ShapeDtypeStruct(w2d.shape, jnp.float32)]
-    outs = pl.pallas_call(
-        apply_kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[block] * len(out_shape),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope(scopes.SEG_APPLY):
+        outs = pl.pallas_call(
+            apply_kernel,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[block] * len(out_shape),
+            out_shape=out_shape,
+            interpret=interpret,
+        )(*args)
     if telemetry:
         telem = {"w_norm": wn, "g_norm": bn, "trust_ratio": ratio}
         return tuple(outs[:-1]), outs[-1], telem

@@ -30,10 +30,13 @@ from __future__ import annotations
 
 from typing import Callable, NamedTuple, Optional
 
+import jax
+
 from repro.configs.base import ModelConfig
 from repro.models import encdec as E
 from repro.models import hybrid as H
 from repro.models import transformer as T
+from repro.obs import scopes
 
 
 class Model(NamedTuple):
@@ -96,10 +99,11 @@ def get_model(cfg: ModelConfig) -> Model:
     def loss(params, batch: dict):
         from repro.training import losses
         h, aux = hidden_fn(cfg, params, batch["tokens"], _extra(batch))
-        emb = params["embed"]
-        w = emb["table"].T if cfg.tie_embeddings else emb["head"]
-        ce = losses.fused_ce_from_hidden(h, w.astype(h.dtype),
-                                         batch["labels"])
+        with jax.named_scope(scopes.HEAD_LOSS):
+            emb = params["embed"]
+            w = emb["table"].T if cfg.tie_embeddings else emb["head"]
+            ce = losses.fused_ce_from_hidden(h, w.astype(h.dtype),
+                                             batch["labels"])
         return ce, aux
 
     def init_cache(params, batch_size: int, max_len: int, extra=None):

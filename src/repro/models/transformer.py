@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import moe as M
+from repro.obs import scopes
 
 
 class LMAux(NamedTuple):
@@ -249,7 +250,8 @@ def apply_lm_hidden(cfg: ModelConfig, params: dict, tokens: jnp.ndarray,
     """Backbone forward up to the final norm (no unembed)."""
     groups, kinds = _group_spec(cfg)
     b, s = tokens.shape
-    h = L.embed(params["embed"], cfg, tokens)
+    with jax.named_scope(scopes.EMBED):
+        h = L.embed(params["embed"], cfg, tokens)
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     masks = {"global": ("causal", None),
              "local": ("causal", cfg.sliding_window)
@@ -262,9 +264,11 @@ def apply_lm_hidden(cfg: ModelConfig, params: dict, tokens: jnp.ndarray,
                               masks, kv_src, aux)
         return (h, aux), None
 
-    h, aux = scan_layers(body, (h, zero_aux()), params["groups"],
-                         cfg.remat)
-    return L.norm(cfg, params["final_norm"], h), aux
+    with jax.named_scope(scopes.LAYERS):
+        h, aux = scan_layers(body, (h, zero_aux()), params["groups"],
+                             cfg.remat)
+    with jax.named_scope(scopes.HEAD_LOSS):
+        return L.norm(cfg, params["final_norm"], h), aux
 
 
 def apply_lm(cfg: ModelConfig, params: dict, tokens: jnp.ndarray,

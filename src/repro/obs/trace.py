@@ -22,11 +22,20 @@ Chrome/Perfetto-loadable timeline; ``tools/obs_report.py`` summarizes
 the per-phase breakdown; ``repro.diagnostics.sink.validate_jsonl``
 schema-checks the records.
 
+Profiler clock: every span of an enabled tracer also enters a
+``jax.profiler.TraceAnnotation`` of its name, so while a profiler
+trace runs the spans land in it on the device trace's own clock, and
+:meth:`Tracer.step` marks each loop iteration with a
+``jax.profiler.StepTraceAnnotation``. Outside a profile an annotation
+costs about as much as an empty ``with``. ``jax`` is imported only by
+the first annotation: the module itself stays pure stdlib.
+
 Overhead: a disabled tracer (or the shared :data:`NULL`) returns one
-shared ``nullcontext`` from :meth:`span` — no allocation, no clock
-read.  An enabled span costs two ``perf_counter_ns`` calls and one
-deque append (~1 µs); the budget test in ``tests/test_obs.py`` holds
-the fully-traced sync fit loop within 3% of the untraced one.
+shared ``nullcontext`` from :meth:`span` and :meth:`step` — no
+allocation, no clock read, no annotation.  An enabled span costs two
+``perf_counter_ns`` calls and one deque append (~1 µs); the budget test
+in ``tests/test_obs.py`` holds the fully-traced sync fit loop within 3%
+of the untraced one.
 """
 from __future__ import annotations
 
@@ -42,10 +51,16 @@ KINDS = ("span", "instant", "counter")
 _NULL_CTX = contextlib.nullcontext()
 
 
-class _Span:
-    """Context manager recording one span event on exit."""
+def _profiler():
+    from jax import profiler
+    return profiler
 
-    __slots__ = ("_tracer", "_name", "_step", "_attrs", "_start")
+
+class _Span:
+    """Context manager recording one span event on exit, inside a
+    profiler annotation of the same name."""
+
+    __slots__ = ("_tracer", "_name", "_step", "_attrs", "_start", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, step, attrs):
         self._tracer = tracer
@@ -54,6 +69,8 @@ class _Span:
         self._attrs = attrs
 
     def __enter__(self) -> "_Span":
+        self._ann = _profiler().TraceAnnotation(self._name)
+        self._ann.__enter__()
         self._start = time.perf_counter_ns()
         return self
 
@@ -64,6 +81,7 @@ class _Span:
             "span", self._name, self._step,
             (self._start - t._t0) / 1e3, (end - self._start) / 1e3,
             threading.current_thread().name, self._attrs))
+        self._ann.__exit__(*exc)
 
 
 class Tracer:
@@ -71,7 +89,9 @@ class Tracer:
 
     ``capacity`` bounds the in-memory event count (FIFO eviction);
     ``enabled=False`` turns every :meth:`span` into the shared no-op
-    context manager, so call sites never branch.  Thread-compat: the
+    context manager, so call sites never branch; an enabled tracer
+    writes each span into a running profiler trace as well (module
+    docstring).  Thread-compat: the
     ring is a ``deque`` (append is atomic under the GIL) — producer
     threads (:class:`~repro.data.pipeline.PrefetchingStream`) and the
     dispatch loop trace into the same ring; each event carries its
@@ -92,6 +112,14 @@ class Tracer:
         if not self.enabled:
             return _NULL_CTX
         return _Span(self, name, step, attrs)
+
+    def step(self, name: str, step_num: int):
+        """Context manager marking one iteration of a step loop as a
+        profiler step (``jax.profiler.StepTraceAnnotation``); records
+        nothing in the ring."""
+        if not self.enabled:
+            return _NULL_CTX
+        return _profiler().StepTraceAnnotation(name, step_num=step_num)
 
     def instant(self, name: str, *, step: Optional[int] = None,
                 **attrs) -> None:

@@ -63,6 +63,7 @@ from repro.diagnostics import probes as probes_lib
 from repro.diagnostics import sink as sinks
 from repro.models.registry import Model
 from repro.obs import layerwise as obs_layerwise
+from repro.obs import scopes
 from repro.obs import trace as obs_trace
 from repro.training import tasks
 from repro.training.losses import WeightedMean
@@ -76,40 +77,43 @@ def _accumulate(grad_fn: Callable, params, batch, accum_steps: int):
     microbatch of activations plus one f32 grad accumulator, independent
     of K (and therefore of the global batch size).
     """
-    hvp_lib.check_stacked(batch, accum_steps)
+    with jax.named_scope(scopes.GRAD_ACCUM):
+        hvp_lib.check_stacked(batch, accum_steps)
 
-    # shapes only — establishes the metrics-dict structure for the carry
-    mb0 = jax.tree_util.tree_map(lambda x: x[0], batch)
-    (_, metrics_shape), _ = jax.eval_shape(grad_fn, params, mb0)
+        # shapes only — establishes the metrics-dict structure for the
+        # carry
+        mb0 = jax.tree_util.tree_map(lambda x: x[0], batch)
+        (_, metrics_shape), _ = jax.eval_shape(grad_fn, params, mb0)
 
-    def body(carry, microbatch):
-        grad_acc, loss_acc, metric_acc = carry
-        (loss, metrics), grads = grad_fn(params, microbatch)
-        grad_acc = jax.tree_util.tree_map(
-            lambda a, g: a + g.astype(jnp.float32), grad_acc, grads)
-        loss_acc = loss_acc.add(loss)
-        metric_acc = jax.tree_util.tree_map(
-            lambda a, v: a.add(v), metric_acc, metrics,
+        def body(carry, microbatch):
+            grad_acc, loss_acc, metric_acc = carry
+            (loss, metrics), grads = grad_fn(params, microbatch)
+            grad_acc = jax.tree_util.tree_map(
+                lambda a, g: a + g.astype(jnp.float32), grad_acc, grads)
+            loss_acc = loss_acc.add(loss)
+            metric_acc = jax.tree_util.tree_map(
+                lambda a, v: a.add(v), metric_acc, metrics,
+                is_leaf=lambda x: isinstance(x, WeightedMean))
+            return (grad_acc, loss_acc, metric_acc), None
+
+        carry0 = (
+            jax.tree_util.tree_map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), params),
+            WeightedMean.zero(),
+            # metric accumulators take the metric's own shape (metrics
+            # need not be scalars — e.g. per-class error vectors)
+            jax.tree_util.tree_map(
+                lambda s: WeightedMean(jnp.zeros(s.shape, jnp.float32),
+                                       jnp.zeros((), jnp.float32)),
+                metrics_shape),
+        )
+        (grad_sum, loss_acc, metric_acc), _ = jax.lax.scan(body, carry0,
+                                                           batch)
+        grads = jax.tree_util.tree_map(lambda g: g / accum_steps, grad_sum)
+        metrics = jax.tree_util.tree_map(
+            lambda a: a.result(), metric_acc,
             is_leaf=lambda x: isinstance(x, WeightedMean))
-        return (grad_acc, loss_acc, metric_acc), None
-
-    carry0 = (
-        jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params),
-        WeightedMean.zero(),
-        # metric accumulators take the metric's own shape (metrics need
-        # not be scalars — e.g. per-class error vectors)
-        jax.tree_util.tree_map(
-            lambda s: WeightedMean(jnp.zeros(s.shape, jnp.float32),
-                                   jnp.zeros((), jnp.float32)),
-            metrics_shape),
-    )
-    (grad_sum, loss_acc, metric_acc), _ = jax.lax.scan(body, carry0, batch)
-    grads = jax.tree_util.tree_map(lambda g: g / accum_steps, grad_sum)
-    metrics = jax.tree_util.tree_map(
-        lambda a: a.result(), metric_acc,
-        is_leaf=lambda x: isinstance(x, WeightedMean))
-    return loss_acc.result(), metrics, grads
+        return loss_acc.result(), metrics, grads
 
 
 def _check_divisible(batch, accum_steps: int, dp: int, axes) -> None:
@@ -148,8 +152,9 @@ def _sharded_grad_fn(task, mesh: Mesh, axes, accum_steps: int):
         def pm(x):
             return jax.lax.pmean(jnp.asarray(x, jnp.float32), axes)
 
-        return (pm(loss), jax.tree_util.tree_map(pm, metrics),
-                jax.tree_util.tree_map(pm, grads))
+        with jax.named_scope(scopes.GRAD_PMEAN):
+            return (pm(loss), jax.tree_util.tree_map(pm, metrics),
+                    jax.tree_util.tree_map(pm, grads))
 
     bspec = pipeline.batch_axes_pspec(axes, accum_steps)
     return jax.shard_map(local, mesh=mesh, in_specs=(P(), bspec),
@@ -249,17 +254,21 @@ def make_train_step(task: Union[tasks.Task, Model],
             raise ValueError(
                 f"task {task.name!r} metrics {sorted(clash)} collide with "
                 f"trainer-reserved metric names")
-        updates, opt_state, tap = apply_optimizer(grads, state.opt_state,
-                                                  state.params)
-        params = apply_updates(state.params, updates)
-        metrics = {"loss": loss, **task_metrics,
-                   "grad_norm": instrumentation.global_norm(grads)}
-        for k, v in tap.items():
-            metrics[f"{obs_layerwise.PREFIX}{k}"] = v
-        if record_norms:
-            # on the accumulated grads: Fig. 2 traces see the global batch
-            metrics["layer_norms"] = instrumentation.layer_norms(
-                state.params, grads)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state, tap = apply_optimizer(
+                grads, state.opt_state, state.params)
+            with jax.named_scope(scopes.APPLY_UPDATES):
+                params = apply_updates(state.params, updates)
+        with jax.named_scope(scopes.STEP_METRICS):
+            metrics = {"loss": loss, **task_metrics,
+                       "grad_norm": instrumentation.global_norm(grads)}
+            for k, v in tap.items():
+                metrics[f"{obs_layerwise.PREFIX}{k}"] = v
+            if record_norms:
+                # on the accumulated grads: Fig. 2 traces see the
+                # global batch
+                metrics["layer_norms"] = instrumentation.layer_norms(
+                    state.params, grads)
         return TrainState(state.step + 1, params, opt_state), metrics
 
     return train_step
@@ -480,7 +489,9 @@ def fit(train_step: Optional[Callable], state: TrainState, batches,
       ``device_get``, or the synchronous path's per-step one),
       ``probe`` / ``controller`` spans.  Export the ring afterwards
       with ``tracer.export(sink)`` / render with
-      ``tools/render_trace.py``.
+      ``tools/render_trace.py``.  The tracer also puts each span, and
+      each iteration as a ``"train"`` ``StepTraceAnnotation``, into a
+      running profiler trace.
     * ``profiler=`` — a :class:`repro.obs.profiler.StepProfiler`
       (``obs.profile(logdir, start=, steps=)``); ``profiler.step(i)``
       runs each iteration and ``close()`` fires in the ``finally``.
@@ -561,72 +572,73 @@ def fit(train_step: Optional[Callable], state: TrainState, batches,
         for i in range(num_steps):
             if profiler is not None:
                 profiler.step(i)
-            # read the target BEFORE the pull: controller retargets
-            # land at the next pull, so this is the batch this step
-            # trains at
-            step_batch_size = controller.global_batch \
-                if controller is not None else None
-            with tracer.span("data_wait", step=i):
-                batch = next(batches)
-            fn = controller.step_fn() if controller is not None \
-                else step_fn
-            with tracer.span("dispatch", step=i):
-                if isinstance(batch, dict):
-                    state, metrics = fn(state, batch)
-                else:
-                    state, metrics = fn(state, *batch)
-            ln = metrics.pop("layer_norms", None)
-            last = i == num_steps - 1
-            if ring is None:
-                if recorder is not None and ln is not None:
-                    recorder.record(i, ln)
-                # scalars -> python floats; non-scalar task metrics
-                # (e.g. per-class vectors) as host numpy arrays
-                with tracer.span("resolve", step=i):
-                    host_metrics = jax.device_get(metrics)
-                emit_train(i, host_metrics, last, step_batch_size)
-            else:
-                if recorder is not None and ln is not None:
-                    ring.append(
-                        i, ln,
-                        lambda s, v, _l: recorder.record(s, v))
-                ring.append(
-                    i, metrics,
-                    lambda s, v, l, _b=step_batch_size:
-                        emit_train(s, v, l, _b),
-                    last=last)
-            for probe in callbacks:
-                prepare = getattr(probe, "prepare", None)
-                if prepare is not None:
-                    # side-stream pre-dispatch hook (e.g. the adaptive
-                    # controller launching its noise probe early)
-                    prepare(i, state)
-                if not probes_lib.probe_due(probe, i):
-                    continue
-                span_name = "controller" if probe is controller \
-                    else "probe"
-                if ring is not None and hasattr(probe, "dispatch") \
-                        and hasattr(probe, "resolve") \
-                        and probe is not controller:
-                    with tracer.span(span_name, step=i,
-                                     probe=getattr(probe, "name", "?"),
-                                     mode="dispatch"):
-                        raw = probe.dispatch(i, state)
-                    ring.append(i, raw,
-                                lambda s, v, l, _p=probe:
-                                    emit_probe(s, _p.resolve(v), l, _p))
-                else:
-                    with tracer.span(span_name, step=i,
-                                     probe=getattr(probe, "name", "?")):
-                        out = probe(i, state)
-                    if ring is None:
-                        emit_probe(i, out, True, probe)
+            with tracer.step("train", i):
+                # read the target BEFORE the pull: controller retargets
+                # land at the next pull, so this is the batch this step
+                # trains at
+                step_batch_size = controller.global_batch \
+                    if controller is not None else None
+                with tracer.span("data_wait", step=i):
+                    batch = next(batches)
+                fn = controller.step_fn() if controller is not None \
+                    else step_fn
+                with tracer.span("dispatch", step=i):
+                    if isinstance(batch, dict):
+                        state, metrics = fn(state, batch)
                     else:
-                        # already-host values ride the ring so records
-                        # keep the synchronous path's exact order
-                        ring.append(i, out,
+                        state, metrics = fn(state, *batch)
+                ln = metrics.pop("layer_norms", None)
+                last = i == num_steps - 1
+                if ring is None:
+                    if recorder is not None and ln is not None:
+                        recorder.record(i, ln)
+                    # scalars -> python floats; non-scalar task metrics
+                    # (e.g. per-class vectors) as host numpy arrays
+                    with tracer.span("resolve", step=i):
+                        host_metrics = jax.device_get(metrics)
+                    emit_train(i, host_metrics, last, step_batch_size)
+                else:
+                    if recorder is not None and ln is not None:
+                        ring.append(
+                            i, ln,
+                            lambda s, v, _l: recorder.record(s, v))
+                    ring.append(
+                        i, metrics,
+                        lambda s, v, l, _b=step_batch_size:
+                            emit_train(s, v, l, _b),
+                        last=last)
+                for probe in callbacks:
+                    prepare = getattr(probe, "prepare", None)
+                    if prepare is not None:
+                        # side-stream pre-dispatch hook (e.g. the adaptive
+                        # controller launching its noise probe early)
+                        prepare(i, state)
+                    if not probes_lib.probe_due(probe, i):
+                        continue
+                    span_name = "controller" if probe is controller \
+                        else "probe"
+                    if ring is not None and hasattr(probe, "dispatch") \
+                            and hasattr(probe, "resolve") \
+                            and probe is not controller:
+                        with tracer.span(span_name, step=i,
+                                         probe=getattr(probe, "name", "?"),
+                                         mode="dispatch"):
+                            raw = probe.dispatch(i, state)
+                        ring.append(i, raw,
                                     lambda s, v, l, _p=probe:
-                                        emit_probe(s, v, l, _p))
+                                        emit_probe(s, _p.resolve(v), l, _p))
+                    else:
+                        with tracer.span(span_name, step=i,
+                                         probe=getattr(probe, "name", "?")):
+                            out = probe(i, state)
+                        if ring is None:
+                            emit_probe(i, out, True, probe)
+                        else:
+                            # already-host values ride the ring so records
+                            # keep the synchronous path's exact order
+                            ring.append(i, out,
+                                        lambda s, v, l, _p=probe:
+                                            emit_probe(s, v, l, _p))
         if ring is not None:
             ring.drain()
     finally:

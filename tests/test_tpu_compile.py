@@ -16,6 +16,7 @@ one).
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +31,7 @@ from repro.kernels import ops
 from repro.kernels.attention_decode import attention_decode_pallas
 from repro.kernels.segmented_update import segmented_update_pallas
 from repro.models import get_model
+from repro.obs import scopes
 from repro.training.train_state import TrainState
 from repro.training.trainer import make_train_step
 
@@ -124,7 +126,8 @@ def test_data_parallel_fused_step_compiles_for_v5e(topo, monkeypatch):
     """The D=4 shard_map train step with the fused optimizer on a 2x2
     mesh: every operand of the optimizer is replicated, and the
     compiler cannot partition a Mosaic call, so the step must run the
-    update per device (``trainer._optimizer_fn``)."""
+    update per device (``trainer._optimizer_fn``). The compiled text
+    carries the step's layer scopes (``repro.obs.scopes``)."""
     # the kernels pick interpret mode from the host's backend, which
     # here is the CPU; this compile targets the described chip
     monkeypatch.setattr(ops, "_interpret", lambda: False)
@@ -148,5 +151,26 @@ def test_data_parallel_fused_step_compiles_for_v5e(topo, monkeypatch):
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
         state, {"tokens": row, "labels": row}).compile()
     assert _native(compiled)
-    assert "all-reduce" in compiled.as_text()
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    # every layer scope of the step, the all-reduce's among them, names
+    # instructions of the compiled program, and the update's two Mosaic
+    # calls sit under their own parts of the optimizer
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in scopes.ALL:
+        assert any(_passes_through(n, scope) for n in names), scope
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2
+    for part in (scopes.SEG_NORM, scopes.SEG_APPLY):
+        assert sum(_passes_through(n, f"{scopes.OPTIMIZER}/{part}")
+                   for n in kernels) == 1, part
 
+
+def _passes_through(op_name: str, scope: str) -> bool:
+    """``op_name`` passes through each segment of ``scope`` in order,
+    bare or inside a transformation (``transpose(jvp(layers))``);
+    other names, such as ``shard_map``, may come between them."""
+    parts = iter(re.split(r"[/()]", op_name))
+    return all(any(p == seg for p in parts) for seg in scope.split("/"))
