@@ -506,6 +506,15 @@ def _shard_table(table: jnp.ndarray) -> jnp.ndarray:
     return table
 
 
+def vocab_shards(vocab: int) -> int:
+    """Shards the ``model`` axis splits a vocabulary of ``vocab`` entries
+    into (the table's and the head's rule in ``launch/sharding.py``); 1
+    when no mesh is declared."""
+    if _MODEL_AXIS_SIZE > 1 and vocab % _MODEL_AXIS_SIZE == 0:
+        return _MODEL_AXIS_SIZE
+    return 1
+
+
 def _vocab_parallel_embed(table: jnp.ndarray, tokens: jnp.ndarray
                           ) -> Optional[jnp.ndarray]:
     """Megatron-style vocab-parallel embedding via shard_map.

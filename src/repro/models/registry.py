@@ -35,6 +35,7 @@ import jax
 from repro.configs.base import ModelConfig
 from repro.models import encdec as E
 from repro.models import hybrid as H
+from repro.models import layers
 from repro.models import transformer as T
 from repro.obs import scopes
 
@@ -102,8 +103,9 @@ def get_model(cfg: ModelConfig) -> Model:
         with jax.named_scope(scopes.HEAD_LOSS):
             emb = params["embed"]
             w = emb["table"].T if cfg.tie_embeddings else emb["head"]
-            ce = losses.fused_ce_from_hidden(h, w.astype(h.dtype),
-                                             batch["labels"])
+            ce = losses.fused_ce_from_hidden(
+                h, w.astype(h.dtype), batch["labels"],
+                vocab_shards=layers.vocab_shards(w.shape[1]))
         return ce, aux
 
     def init_cache(params, batch_size: int, max_len: int, extra=None):

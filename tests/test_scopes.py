@@ -39,10 +39,11 @@ def op_names(hlo_text: str) -> list[str]:
     return re.findall(r'op_name="([^"]*)"', hlo_text)
 
 
-def _tiny_step(accum_steps=2):
+def _tiny_step(accum_steps=2, vocab_size=256):
     cfg = ModelConfig(family="dense", num_layers=2, d_model=64,
                       num_heads=4, num_kv_heads=2, d_ff=128,
-                      vocab_size=256, tie_embeddings=True, remat=True)
+                      vocab_size=vocab_size, tie_embeddings=True,
+                      remat=True)
     model = get_model(cfg)
     opt = build_optimizer("tvlars", total_steps=4, learning_rate=1.0,
                           use_kernel="fused")
@@ -81,6 +82,22 @@ def test_every_scope_names_instructions_of_the_compiled_step():
     # (tests/test_tpu_compile.py compiles that step for four chips)
     for scope in set(scopes.ALL) - {scopes.GRAD_PMEAN}:
         assert any(passes_through(n, scope) for n in names), scope
+
+
+@pytest.mark.parametrize("vocab_size,vocab_loop", [(256, True), (16, False)])
+def test_vocab_chunks_scope_shows_which_ce_loop_runs(vocab_size, vocab_loop):
+    """A microbatch of 2×16 tokens: under a 256-entry vocabulary the
+    cross-entropy loops over vocabulary chunks, and its forward and
+    backward sit under ``head_loss/vocab_chunks``; under 16 entries it
+    keeps the sequence loop, and the scope names nothing."""
+    step, state, batch = _tiny_step(vocab_size=vocab_size)
+    names = op_names(jax.jit(step).lower(state, batch).compile().as_text())
+    scope = f"{scopes.HEAD_LOSS}/{scopes.VOCAB_CHUNKS}"
+    inside = [n for n in names if passes_through(n, scope)]
+    assert bool(inside) == vocab_loop
+    if vocab_loop:
+        assert any("transpose(" in n for n in inside)
+        assert any("transpose(" not in n for n in inside)
 
 
 def test_update_kernels_sit_under_their_scopes():

@@ -207,6 +207,67 @@ print("SHARD_MAP_STEP_MATCHES")
 """
 
 
+VOCAB_CE_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import re
+import sys
+sys.path.insert(0, "src")
+import jax
+import numpy as np
+from repro.configs.base import ModelConfig
+from repro.models import get_model
+from repro.models import layers as layers_lib
+from repro.core import build_optimizer
+from repro.training.train_state import TrainState
+from repro.training.trainer import make_train_step
+from repro.launch import sharding
+from repro.launch.mesh import make_host_mesh
+from repro.data.synthetic import lm_batch
+
+# 2000-entry tied vocabulary, 500 per model shard; 8 rows of 64 tokens
+# (T 512 > D 64: two chunks per shard, 256 and 244, logits recomputed)
+# and of 8 tokens (T 64 ≤ D: one chunk, logits kept)
+cfg = ModelConfig(family="dense", num_layers=2, d_model=64, num_heads=4,
+                  num_kv_heads=2, d_ff=128, vocab_size=2000,
+                  tie_embeddings=True, remat=True)
+m = get_model(cfg)
+opt = build_optimizer("tvlars", total_steps=10, learning_rate=1.0)
+mesh = make_host_mesh(2, 4)
+for seq in (64, 8):
+    toks, labels = lm_batch(jax.random.PRNGKey(1), 8, seq, cfg.vocab_size)
+    batch = {"tokens": toks, "labels": labels}
+    layers_lib.set_batch_sharding(None)
+    state = TrainState.create(m.init(jax.random.PRNGKey(0)), opt)
+    _, ref = jax.jit(make_train_step(m, opt))(state, batch)
+    with mesh:
+        layers_lib.set_batch_sharding(("data",), "model", model_size=4,
+                                      mesh=mesh)
+        assert layers_lib.vocab_shards(cfg.vocab_size) == 4
+        state_sh = sharding.named(
+            mesh, sharding.state_pspecs(mesh, jax.eval_shape(lambda: state),
+                                        fsdp=True))
+        batch_sh = sharding.named(
+            mesh, sharding.batch_pspecs(
+                mesh, jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)))
+        args = (jax.device_put(state, state_sh),
+                jax.device_put(batch, batch_sh))
+        step = jax.jit(make_train_step(m, opt),
+                       in_shardings=(state_sh, batch_sh))
+        text = step.lower(*args).compile().as_text()
+        _, got = step(*args)
+    layers_lib.set_batch_sharding(None)
+    np.testing.assert_allclose(float(got["loss"]), float(ref["loss"]),
+                               rtol=1e-5)
+    permutes = [line for line in text.splitlines()
+                if re.search(r"collective-permute(-start)?\(", line)
+                and "head_loss" in line]
+    assert not permutes, permutes[:2]
+print("VOCAB_CE_STAYS_ON_ITS_SHARDS")
+"""
+
+
 def _run(script: str) -> str:
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
@@ -235,6 +296,16 @@ def test_shard_map_train_step_matches_single_device():
     in-process grid in test_mesh_train.py, so tier-1 covers it without
     the multidevice env flag."""
     assert "SHARD_MAP_STEP_MATCHES" in _run(SHMAP_SCRIPT)
+
+
+@pytest.mark.slow
+def test_vocab_chunked_ce_stays_on_its_shards():
+    """On a (2,4) mesh with the production rules the tied table is split
+    over the ``model`` axis; with fewer tokens than vocabulary entries
+    the CE's vocabulary chunks each take the same columns of every
+    shard, so the loss equals the single device's and no chunk is moved
+    between devices (no collective-permute under ``head_loss``)."""
+    assert "VOCAB_CE_STAYS_ON_ITS_SHARDS" in _run(VOCAB_CE_SCRIPT)
 
 
 def test_pspec_rules_divisibility_guard():

@@ -1,5 +1,6 @@
 """Data pipeline, losses, checkpoint, serving, trainer integration."""
 import os
+import re
 import tempfile
 
 import jax
@@ -18,6 +19,7 @@ from repro.core import build_optimizer
 from repro.data.synthetic import (ClassificationData, batch_iterator,
                                   lm_batch, two_view_batch)
 from repro.models import get_model
+from repro.obs import scopes
 from repro.serving.decode import generate
 from repro.training import losses
 from repro.training.train_state import TrainState
@@ -99,6 +101,133 @@ if st is not None:
 else:
     def test_fused_ce_equals_reference():
         pytest.importorskip("hypothesis")
+
+
+def _ce_loss_and_grads(fn, h, w, y):
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(fn, (0, 1))(h, w, y)
+
+
+@pytest.mark.parametrize("b,s,v,dtype,shards", [
+    # T = B·S < V: the vocabulary loop; d = 16, so T ≤ 16 keeps the logits
+    (1, 8, 1000, "float32", 1),       # one chunk, logits kept
+    (1, 8, 1000, "float32", 2),       # two shards of 500, logits kept
+    (2, 300, 1000, "float32", 1),     # 2 chunks of 384 and a tail of 232
+    (2, 300, 1000, "float32", 4),     # per shard of 250: 128 and 122
+    (1, 700, 1187, "float32", 1),     # 2 chunks of 512 and a tail of 163
+    (2, 300, 1187, "bfloat16", 1),
+    # T ≥ V: the sequence loop
+    (2, 512, 1000, "float32", 1),     # 2 chunks of CE_CHUNK
+    (1, 1300, 1187, "float32", 1),    # S not a multiple of CE_CHUNK
+    (2, 8, 12, "float32", 1),
+])
+def test_fused_ce_grads_equal_autodiff_of_reference(b, s, v, dtype, shards):
+    """Loss, dh and dW of the fused CE against autodiff of
+    ``cross_entropy(h @ w, y)`` in f32 from the same inputs.
+
+    f32 cases agree to float rounding. The bf16 case rounds each logit,
+    dlogit and head-gradient entry to bf16 (8 significant bits, relative
+    error up to 2^-9 ≈ 2e-3) as the training step does: rounding errors
+    of the logits mostly cancel in the mean loss (held to 1e-4
+    relative), while a gradient entry, a sum of rounded terms, is off by
+    up to about 2^-8 of its tensor's largest entry (held to 1e-2 of
+    it)."""
+    rng = np.random.default_rng(b * 7919 + s + v)
+    d = 16
+    h = jnp.asarray(rng.normal(size=(b, s, d)), dtype)
+    w = jnp.asarray(rng.normal(size=(d, v)) * 0.2, dtype)
+    y = jnp.asarray(rng.integers(0, v, (b, s)), jnp.int32)
+    ref, ref_g = _ce_loss_and_grads(
+        lambda h, w, y: losses.cross_entropy(h @ w, y),
+        h.astype(jnp.float32), w.astype(jnp.float32), y)
+    got, got_g = _ce_loss_and_grads(
+        lambda h, w, y: losses.fused_ce_from_hidden(
+            h, w, y, vocab_shards=shards), h, w, y)
+    rtol, frac = (1e-5, 1e-5) if dtype == "float32" else (1e-4, 1e-2)
+    np.testing.assert_allclose(float(got), float(ref), rtol=rtol)
+    for g, r in zip(got_g, ref_g):
+        assert g.dtype == jnp.dtype(dtype)
+        r = np.asarray(r)
+        np.testing.assert_allclose(np.asarray(g, np.float32), r,
+                                   rtol=0, atol=frac * np.abs(r).max())
+
+
+@pytest.mark.parametrize("tokens,vocab,split", [
+    (2048, 151936, (19072, 7, 18432)),     # 151,936 = 128 · 1187
+    (2048, 151936 // 4, (4864, 7, 3936)),  # one of 4 vocabulary shards
+    (32768, 151936, (1280, 118, 896)),
+    (600, 1000, (384, 2, 232)),
+    (700, 1187, (512, 2, 163)),
+    (8, 1000, (1000, 1, 0)),
+    (2048, 4096, (512, 8, 0)),
+])
+def test_vocab_split_covers_the_vocabulary_once(tokens, vocab, split):
+    width, full, tail = losses.vocab_split(tokens, vocab)
+    assert (width, full, tail) == split
+    assert full * width + tail == vocab and tail < width
+    assert width % losses.LANES == 0 or width == vocab
+    assert full + (tail > 0) <= -(-tokens // losses.CE_CHUNK)
+
+
+def _loop_add_sizes(hlo_text: str) -> list[int]:
+    """Element counts of every ``add`` inside a ``while`` body or a
+    computation it calls: what a loop accumulates into."""
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    todo = [c for lines in comps.values() for line in lines
+            for c in re.findall(r"body=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(r"(?:calls|to_apply|body)=%([\w.\-]+)", line)
+    return [int(np.prod([int(x) for x in dims.split(",") if x]))
+            for c in seen for line in comps[c]
+            for dims in re.findall(r"= \w+\[([\d,]*)\]\S* add\(", line)]
+
+
+@pytest.mark.parametrize("s,v,loop,matmuls", [
+    (2048, 4096, "vocab", 4),    # T > D: the backward recomputes logits
+    (64, 4096, "vocab", 3),      # T ≤ D: the forward's logits are kept
+    (2048, 1024, "sequence", 4),
+])
+def test_fused_ce_backward_carries_no_head_gradient(s, v, loop, matmuls):
+    """The compiled grad at (1, s, 64): with T < V no loop adds into a
+    tensor of the head gradient's D·V elements, the head runs at most
+    ``matmuls`` matmuls (forward, the backward's recompute where the
+    logits are not kept, dh, dW) and every dot sits under
+    ``head_loss``. The sequence loop (T ≥ V) does add into such a
+    carry, which shows the check finds one."""
+    b, d = 1, 64
+    h = jax.ShapeDtypeStruct((b, s, d), jnp.float32)
+    w = jax.ShapeDtypeStruct((d, v), jnp.float32)
+    y = jax.ShapeDtypeStruct((b, s), jnp.int32)
+
+    def loss(h, w, y):
+        with jax.named_scope(scopes.HEAD_LOSS):
+            return losses.fused_ce_from_hidden(h, w, y)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(h, w, y).compile()
+    text = compiled.as_text()
+    assert (d * v in _loop_add_sizes(text)) == (loop == "sequence")
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert cost["flops"] <= matmuls * 2 * b * s * d * v * 1.05
+    dots = [line for line in text.splitlines()
+            if re.search(r"= \S+ dot\(", line)]
+    assert dots
+    for line in dots:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert scopes.HEAD_LOSS in re.split(r"[/()]", op_name), op_name
 
 
 def test_barlow_twins_identical_views_low_loss():
