@@ -36,7 +36,6 @@ from chipbench import common  # noqa: E402
 
 def train_control(config: dict, traffic: dict, seeds) -> None:
     from chipbench import reference, train
-    arch = reference.arch_of(config)
     hp = train.hyper(config, traffic)
     K, mb, D = (traffic["accum_steps"], traffic["microbatch"],
                 traffic["data_parallel"])
@@ -52,17 +51,17 @@ def train_control(config: dict, traffic: dict, seeds) -> None:
         variants["no_exchange"] = ("f32", lambda i, r: r[first, :])
     for seed in seeds:
         key = reference.base_key(seed)
-        rows_of = train.reference_rows(arch, key, rows, S)
+        rows_of = train.reference_rows(config, key, rows, S)
         t = time.perf_counter()
-        ref = reference.train_readings(arch, config["init"], hp, key,
-                                       rows_of, S, steps, "f32")
+        ref = reference.train_readings(config, hp, key, rows_of, S, steps,
+                                       "f32")
         print(json.dumps({"seed": seed, "variant": "reference",
                           "seconds": time.perf_counter() - t,
                           "losses": ref["losses"]}), flush=True)
         for name, (prec, keep) in variants.items():
-            got = reference.train_readings(arch, config["init"], hp, key,
-                                           rows_of, S, steps, prec, keep)
-            check = train.numbers(traffic, arch, hp, got["losses"],
+            got = reference.train_readings(config, hp, key, rows_of, S,
+                                           steps, prec, keep)
+            check = train.numbers(traffic, config, hp, got["losses"],
                                   got["first"], got["delta_norms"], ref)
             print(json.dumps({"seed": seed, "variant": name,
                               "readings": check.report()}), flush=True)

@@ -1,36 +1,28 @@
-"""The bridge to the system under test: the program's model
-configuration built from a configuration file, a check that the program
-stores its weights in the layout the benchmark makes them in, and the
+"""The bridge to the system under test: the program's model of a
+configuration, a check that the program stores its weights in the
+layout the benchmark makes them in, and the
 conversion of host span records to the trace clock."""
 from __future__ import annotations
 
 import time
 
-from chipbench import reference
+from chipbench import arch, reference
 
 
-def model_config(config: dict):
-    from repro.configs.base import ModelConfig
-    p = config["program"]
-    return ModelConfig(
-        arch_id=config["name"], family="dense", source=config["source"],
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        qkv_bias=p["qkv_bias"], tie_embeddings=config["tie_word_embeddings"],
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        param_dtype=p["param_dtype"], compute_dtype=p["compute_dtype"],
-        remat=p["remat"])
+def model_of(config: dict):
+    """The program's model of the configuration, built from the
+    architecture module's ``program_config``, its layout checked."""
+    from repro.models import get_model
+    model = get_model(arch.load(config).program_config(config))
+    check_layout(model, config)
+    return model
 
 
-def check_layout(model, arch: reference.Arch) -> None:
+def check_layout(model, config: dict) -> None:
     """The program's weight tree has exactly the benchmark's leaves."""
     import jax
     want = {reference.leaf_name(path): shape
-            for path, shape, _ in reference.leaf_table(arch)}
+            for path, shape, _ in arch.load(config).leaves(config)}
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     got = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:

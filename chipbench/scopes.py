@@ -36,6 +36,9 @@ HLO_PROTO_STAT = "Hlo Proto"
 STEP_MODULE = r"^jit_train_step\("
 # ops whose interval holds the ops of their bodies
 CONTROL_OPCODES = ("while", "conditional", "call")
+# a TPU trace names a Pallas call after its enclosing function; its op
+# text carries the custom-call target
+PALLAS_CALL = r'custom_call_target="tpu_custom_call"'
 
 def known_scopes() -> Optional[tuple]:
     """The program's scope paths; None when it names none."""
@@ -227,6 +230,15 @@ def seconds_by_scope(trace: xplane.Trace, runs, modules,
     return {s: ns / k / 1e9 for s, ns in acc.items()}
 
 
+def compiled(run: dict) -> tuple[dict, dict]:
+    """(``module_runs``, ``hlo_modules``) of a traced run's trace file,
+    kept in the run's reader inputs for the next reader."""
+    if "compiled" not in run:
+        path = trace_file(run["logdir"])
+        run["compiled"] = (module_runs(path), hlo_modules(path))
+    return run["compiled"]
+
+
 def reduction(run: dict) -> Optional[dict[Optional[str], float]]:
     """``seconds_by_scope`` of a traced training run, kept in the run's
     reader inputs for the next reader; None when the program names no
@@ -235,9 +247,9 @@ def reduction(run: dict) -> Optional[dict[Optional[str], float]]:
     if run["kind"] != "train" or known is None:
         return None
     if "seconds_by_scope" not in run:
-        path = trace_file(run["logdir"])
-        run["seconds_by_scope"] = seconds_by_scope(
-            run["trace"], module_runs(path), hlo_modules(path), known)
+        runs, modules = compiled(run)
+        run["seconds_by_scope"] = seconds_by_scope(run["trace"], runs,
+                                                   modules, known)
     return run["seconds_by_scope"]
 
 
@@ -255,6 +267,32 @@ def ms_per_step(run: dict, prefix: Optional[str]) -> Optional[float]:
         total = sum(v for s, v in by.items() if s is not None
                     and (s == prefix or s.startswith(prefix + "/")))
     return total / run["steps"] * 1e3
+
+
+def pallas_calls(run: dict, names) -> Optional[tuple[int, float]]:
+    """(count, device seconds averaged over the devices) of the train
+    step's Pallas calls (``PALLAS_CALL`` in the op's text) whose
+    innermost scope is one of ``names`` or nested in one; None when the
+    program names no scopes. A model's own kernels, under other scopes,
+    are not counted."""
+    known = known_scopes()
+    if run["kind"] != "train" or known is None:
+        return None
+    missing = [n for n in names if n not in known]
+    if missing:
+        raise ValueError(f"the program names no scope {missing!r}")
+    runs, modules = compiled(run)
+    rx = re.compile(PALLAS_CALL)
+    count = ns = 0
+    for _, op, module in step_ops(run["trace"], runs, modules):
+        if not rx.search(op.label):
+            continue
+        scope = scope_of(modules[module][op.name][1], known)
+        if scope is not None and any(
+                scope == n or scope.startswith(n + "/") for n in names):
+            count += 1
+            ns += op.end - op.start
+    return count, ns / max(len(run["trace"].devices), 1) / 1e9
 
 
 # ----------------------------------------------------------- annotations

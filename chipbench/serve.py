@@ -75,13 +75,10 @@ def make_engine(config: dict, seed: int, tracer=None) -> tuple:
     import jax
     import jax.numpy as jnp
     from repro import serving
-    from repro.models import get_model
 
-    arch = reference.arch_of(config)
-    model = get_model(program.model_config(config))
-    program.check_layout(model, arch)
+    model = program.model_of(config)
     params = jax.jit(reference.weights_fn(
-        arch, config["init"], jnp.dtype(config["program"]["param_dtype"])))(
+        config, jnp.dtype(config["program"]["param_dtype"])))(
             reference.base_key(seed))
     sc = config["serve"]
     eng = serving.Engine(model, params, serving.ServeConfig(
@@ -180,20 +177,20 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
     import jax
     from repro.obs.trace import Tracer
 
-    arch, shape = reference.arch_of(config), flops.shape_of(config)
+    vocab = config["vocab_size"]
     clock = tracer = None
     if trace:
         clock = program.SpanClock()
         tracer = Tracer()
     params, eng = make_engine(config, seed, tracer)
-    warm_up(eng, traffic, arch.vocab)
+    warm_up(eng, traffic, vocab)
     compiled = (eng.prefill_compilations, eng.decode_compilations)
     if engine_hook is not None:
         engine_hook(eng)
 
     length = traffic["trace_warm_seconds"] + traffic["trace_seconds"] \
         if trace else seconds
-    due, prompts, outs = schedule(traffic, seed, length, arch.vocab)
+    due, prompts, outs = schedule(traffic, seed, length, vocab)
     reqs = [Req(d, p, o) for d, p, o in zip(due, prompts, outs)]
     logdir = os.path.join(common.TRACE_DIR, "serve")
     marks = {}
@@ -240,14 +237,14 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
     else:
         lo, hi = marks["t0"], marks["t1"]
         traced = [s for s in out["steps"] if s["traced"]]
-        fl = sum(flops.prefill_flops(shape, p) for s in traced
+        fl = sum(flops.prefill_flops(config, p) for s in traced
                  for p in s["prompts"])
-        fl += sum(flops.decode_flops(shape, c) for s in traced
+        fl += sum(flops.decode_flops(config, c) for s in traced
                   for c in s["contexts"])
         reader = {"kind": "serve", "logdir": logdir, "clock": clock,
                   "records": records, "steps": len(traced), "flops": fl,
                   "decode_bytes": sum(flops.decode_attention_bytes(
-                      shape, s["contexts"]) for s in traced),
+                      config, s["contexts"]) for s in traced),
                   "arrival_lag_s": [r.submit - (t0 + r.due) for r in reqs
                                     if r.submit is not None
                                     and lo <= r.submit <= hi],
@@ -261,7 +258,7 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
     failed = 0
     if out["finished"]:
         sampled = sample(out["finished"], traffic, seed)
-        gaps = logit_gaps(config, traffic, arch, params, sampled)
+        gaps = logit_gaps(config, traffic, params, sampled)
         failed = sum(g > traffic["limits"]["logit_gap"] for g in gaps)
         check.number("logit_gap", max(gaps), traffic["limits"]["logit_gap"])
         if after is not None:
@@ -295,11 +292,11 @@ def padded(traffic: dict, r) -> tuple:
     return seq[:-1], seq[1:], len(r.prompt) - 1
 
 
-def logit_gaps(config, traffic, arch, params, reqs,
+def logit_gaps(config, traffic, params, reqs,
                precision: str = "f32") -> list[float]:
     """Per request, the widest gap of a served token below the
     reference's best logit at its position."""
-    fn = reference.gap_fn(arch, precision)
+    fn = reference.gap_fn(config, precision)
     out = []
     for r in reqs:
         ids, nxt, first = padded(traffic, r)
@@ -313,9 +310,8 @@ def control_gaps(config, traffic, params, reqs) -> list[float]:
     """Per request, the widest gap below the float32 reference's best
     logit of the tokens the float8 reference puts first at the served
     positions of the same prompts and tokens."""
-    arch = reference.arch_of(config)
-    exact = reference.gap_fn(arch, "f32")
-    low = reference.gap_fn(arch, "fp8")
+    exact = reference.gap_fn(config, "f32")
+    low = reference.gap_fn(config, "fp8")
     out = []
     for r in reqs:
         ids, nxt, first = padded(traffic, r)

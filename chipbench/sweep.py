@@ -38,18 +38,18 @@ def main() -> int:
     common.setup_src_path()
     common.enable_compile_cache()
     devices = common.require_chips(w["chips"])
-    from chipbench import reference, serve
+    from chipbench import serve
 
-    arch = reference.arch_of(config)
+    vocab = config["vocab_size"]
     _, eng = serve.make_engine(config, args.seed)
     t = time.perf_counter()
-    serve.warm_up(eng, traffic, arch.vocab)
+    serve.warm_up(eng, traffic, vocab)
     print(f"warm-up {time.perf_counter() - t:.1f} s", flush=True)
     sustained = []
     for rate in args.rates:
         tr = dict(traffic, rate_per_s=rate)
         due, prompts, outs = serve.schedule(tr, args.seed, args.seconds,
-                                            arch.vocab)
+                                            vocab)
         reqs = [serve.Req(d, p, o) for d, p, o in zip(due, prompts, outs)]
         t0 = time.perf_counter()
         close = t0 + args.seconds

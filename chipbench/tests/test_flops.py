@@ -10,23 +10,24 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(os.path.dirname(HERE)))
 
 from chipbench import flops  # noqa: E402
+from chipbench.arch import qwen2  # noqa: E402
 
 
-def shape(name: str) -> flops.Shape:
+def shape(name: str) -> dict:
     with open(os.path.join(HERE, "..", "configs", f"{name}.json")) as f:
-        return flops.shape_of(json.load(f))
+        return json.load(f)
 
 
 def test_train_config_parameter_counts():
     s = shape("qwen2.5-3b-train-3L")
     # per layer: q, o 2048x2048 each; k, v 2048x256 each; MLP 3x2048x11008
     layer = 2 * 2048 * 2048 + 2 * 2048 * 256 + 3 * 2048 * 11008
-    assert flops.layer_matmul_params(s) == layer == 77_070_336
-    assert flops.head_params(s) == 151_936 * 2048 == 311_164_928
-    assert flops.matmul_params(s) == 3 * layer + 311_164_928
+    assert qwen2.layer_matmul_params(s) == layer == 77_070_336
+    assert qwen2.head_params(s) == 151_936 * 2048 == 311_164_928
+    assert qwen2.matmul_params_per_token(s) == 3 * layer + 311_164_928
     # plus q/k/v biases (2048 + 2 x 256) and two norm scales per layer,
     # and the final norm: the 542,397,952 parameters the program holds
-    assert flops.param_count(s) == 542_397_952
+    assert qwen2.param_count(s) == 542_397_952
 
 
 def test_train_flops_per_token():
@@ -43,11 +44,11 @@ def test_update_bytes_are_five_f32_passes():
 
 def test_serve_config_counts():
     s = shape("qwen2.5-3b-serve")
-    assert flops.param_count(s) == 3_085_938_688
+    assert qwen2.param_count(s) == 3_085_938_688
     # 36 layers x 2 KV heads x 128 x (K, V) x 2 bytes
-    assert flops.kv_bytes_per_token(s) == 36_864
+    assert qwen2.kv_bytes_per_token(s, 2) == 36_864
     # the pool: 32 slots x 2048 tokens
-    assert flops.kv_bytes_per_token(s) * 32 * 2048 == 2_415_919_104
+    assert qwen2.kv_bytes_per_token(s, 2) * 32 * 2048 == 2_415_919_104
 
 
 def test_decode_attention_bytes():

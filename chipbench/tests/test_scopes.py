@@ -179,7 +179,9 @@ def test_readers_report_nothing_for_a_program_without_scopes(
     run = _run(tmp_path)
     for name in ("train.embed_ms", "train.layers_ms",
                  "train.head_loss_ms", "train.grad_accum_ms",
-                 "optimizer.update_ms", "train.unscoped_ms"):
+                 "optimizer.update_ms", "train.unscoped_ms",
+                 "segmented_update.kernel_ms",
+                 "segmented_update.hbm_roofline"):
         assert common.read_metric(name, run) is None
     # nor is there a resolve annotation in the recorded trace
     assert common.read_metric("train.resolve_idle_ms", run) is None
@@ -212,3 +214,48 @@ def test_resolve_reader_reads_idle_per_step(tmp_path, monkeypatch):
         else real(path, names))
     assert common.read_metric("train.resolve_idle_ms", run) == \
         pytest.approx(scopes.idle_inside(run["trace"], wait) / 3 * 1e3)
+
+
+# a step with the optimizer's two Pallas calls and one of the model's own
+# (a grouped expert matmul, say), which the update's readers must not count
+KERNEL_PROTO = _hlo_proto(
+    [_instruction("seg_norm.1", "custom-call",
+                  "jit(train_step)/optimizer/seg_norm/pallas_call"),
+     _instruction("seg_apply.2", "custom-call",
+                  "jit(train_step)/optimizer/shard_map/seg_apply/"
+                  "pallas_call"),
+     _instruction("experts.3", "custom-call",
+                  "jit(train_step)/grad_accum/while/body/layers/experts/"
+                  "pallas_call"),
+     _instruction("add_fusion", "fusion",
+                  "jit(train_step)/optimizer/seg_apply/add")])
+PALLAS_TEXT = ' = f32[8] custom-call(), custom_call_target="tpu_custom_call"'
+
+
+def _kernel_run(ops, steps=2):
+    tr = xplane.Trace((0, 100), {0: ops, 1: ops})
+    runs = {d: [(0, 100, STEP)] for d in (0, 1)}
+    return {"kind": "train", "steps": steps, "trace": tr,
+            "update_bytes": 10.0, "peaks": {"hbm_bytes_per_s": 1e9},
+            "compiled": (runs, {STEP: scopes.instructions(KERNEL_PROTO)})}
+
+
+def test_update_readers_count_only_the_optimizer_kernels():
+    ops = [xplane.Op(0, 10, "seg_norm.1", "%seg_norm.1" + PALLAS_TEXT),
+           xplane.Op(10, 30, "add_fusion", "%add_fusion = fusion()"),
+           xplane.Op(30, 50, "seg_apply.2", "%seg_apply.2" + PALLAS_TEXT),
+           xplane.Op(50, 90, "experts.3", "%experts.3" + PALLAS_TEXT)]
+    run = _kernel_run(ops)
+    assert scopes.pallas_calls(run, ("optimizer/seg_norm",
+                                     "optimizer/seg_apply")) == \
+        (4, pytest.approx(30e-9))
+    # 30 ns over 2 steps; the expert kernel's 40 ns and the fusion under
+    # seg_apply are not the update's
+    assert common.read_metric("segmented_update.kernel_ms", run) == \
+        pytest.approx(15e-6)
+    assert common.read_metric("segmented_update.hbm_roofline", run) == \
+        pytest.approx(100.0 * (10.0 / 1e9) / 15e-9)
+    # a step without the update's kernels reads nothing
+    run = _kernel_run(ops[3:])
+    assert common.read_metric("segmented_update.kernel_ms", run) is None
+    assert common.read_metric("segmented_update.hbm_roofline", run) is None

@@ -15,7 +15,9 @@ after one step) and the change of the weights over the checked steps,
 each of the last two per stored tensor against the larger of that
 tensor's reference norm and the median tensor's. Tensors whose reference
 gradient is under a thousandth of the median tensor's are left out of
-those two (a key bias, which softmax ignores, is one).
+those two (a key bias, which softmax ignores, is one). A tensor that
+the architecture marks ``held`` (moved only by its ``after_step``) has
+no first gradient to read back; its change is compared.
 """
 from __future__ import annotations
 
@@ -25,7 +27,7 @@ import os
 import shutil
 import time
 
-from chipbench import common, flops, program, reference, xplane
+from chipbench import arch, common, flops, program, reference, xplane
 
 # largest |g| / (wd |w|) at which a first-gradient norm is read back
 MAX_AMPLIFICATION = 100.0
@@ -81,19 +83,16 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
     from jax.sharding import NamedSharding, PartitionSpec as P
     from jax.sharding import SingleDeviceSharding
     from repro.launch.mesh import make_data_mesh
-    from repro.models import get_model
     from repro.obs.trace import Tracer
     from repro.training import trainer
     from repro.training.train_state import TrainState
 
-    arch, shape = reference.arch_of(config), flops.shape_of(config)
     K, mb, D = (traffic["accum_steps"], traffic["microbatch"],
                 traffic["data_parallel"])
     S, checked = traffic["seq_len"], traffic["checked_steps"]
     rows = K * mb * D
     hp = hyper(config, traffic)
-    model = get_model(program.model_config(config))
-    program.check_layout(model, arch)
+    model = program.model_of(config)
     opt = build_optimizer(config, hp)
     if D > 1:
         mesh = make_data_mesh(D)
@@ -103,12 +102,12 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
         mesh = None
         rep = bsh = SingleDeviceSharding(devices[0])
     key = reference.base_key(seed)
-    init = reference.weights_fn(arch, config["init"], jnp.float32)
+    init = reference.weights_fn(config, jnp.float32)
     state = jax.jit(lambda k: TrainState.create(init(k), opt),
                     out_shardings=rep)(key)
 
     def batch(k, i):
-        ids = reference.step_tokens(k, i, rows, S, arch.vocab)
+        ids = reference.step_tokens(k, i, rows, S, config["vocab_size"])
         b = {"tokens": ids[:, :-1], "labels": ids[:, 1:]}
         if K > 1:
             b = {n: x.reshape(K, rows // K, S) for n, x in b.items()}
@@ -119,11 +118,12 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
     train_step = factory(model, opt, accum_steps=K, mesh=mesh)
 
     def first_stats(p, k):
-        return reference.first_update_stats(arch, hp, p, init(k))
+        return reference.first_update_stats(config, hp, p, init(k))
 
     def change(p, k):
         return [jnp.linalg.norm(w - w0) for w, w0 in
-                zip(reference.flat(p, arch), reference.flat(init(k), arch))]
+                zip(reference.flat(p, config),
+                    reference.flat(init(k), config))]
 
     first_stats, change = jax.jit(first_stats), jax.jit(change)
     losses, times, first = [], [], None
@@ -172,15 +172,15 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
                   "records": tracer.events(), "steps": n,
                   "tokens": n * tokens_per_step,
                   "flops": n * tokens_per_step
-                  * flops.train_flops_per_token(shape, S),
-                  "update_bytes": flops.update_bytes(shape),
+                  * flops.train_flops_per_token(config, S),
+                  "update_bytes": flops.update_bytes(config),
                   "chips": len(devices)}
     bad = sum(1 for h in hist if not math.isfinite(float(h["loss"])))
     peak = common.memory_peak(devices)
     del state, hist, feed, train_step
     gc.collect()
 
-    check = compare(config, traffic, arch, hp, key, losses, first, moved)
+    check = compare(config, traffic, hp, key, losses, first, moved)
     check.require(bad == 0, f"{bad} window steps gave a non-finite loss")
     result = {"attempted": n, "failed": bad, "metrics": metrics,
               "device": {**common.device_info(devices),
@@ -188,27 +188,32 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
     return result, check, reader
 
 
-def reference_rows(arch, key, rows: int, seq: int):
+def reference_rows(config: dict, key, rows: int, seq: int):
     import jax
-    make = jax.jit(lambda k, i: reference.step_tokens(k, i, rows, seq,
-                                                      arch.vocab))
     import jax.numpy as jnp
+    make = jax.jit(lambda k, i: reference.step_tokens(
+        k, i, rows, seq, config["vocab_size"]))
     return lambda i: make(key, jnp.int32(i))
 
 
-def program_grad_norms(arch, hp, first) -> list[float]:
-    return [reference.first_grad_norm(hp, len(shape) >= 2, *stats)
-            for (_, shape, _), stats in zip(reference.leaf_table(arch),
-                                            first)]
+def program_grad_norms(config, hp, first) -> list[float]:
+    """The first gradient's norm of each leaf the optimizer updates, read
+    back from its first update (NaN for a ``held`` leaf)."""
+    return [math.nan if role == "held" else
+            reference.first_grad_norm(hp, role == "adapt", *stats)
+            for role, stats in zip(arch.roles(config), first)]
 
 
-def kept_leaves(ref_grads) -> list[bool]:
-    """Tensors whose reference gradient is not nought to rounding."""
-    med = sorted(ref_grads)[len(ref_grads) // 2]
-    return [g >= 1e-3 * med for g in ref_grads]
+def kept_leaves(roles, ref_grads) -> list[bool]:
+    """Tensors whose change is compared: every ``held`` one, and those
+    the optimizer updates whose reference gradient is not nought to
+    rounding (under a thousandth of the median such tensor's)."""
+    moved = sorted(g for g, r in zip(ref_grads, roles) if r != "held")
+    med = moved[len(moved) // 2]
+    return [r == "held" or g >= 1e-3 * med for r, g in zip(roles, ref_grads)]
 
 
-def resolvable(arch, hp, ref_grads, first) -> list[bool]:
+def resolvable(roles, hp, ref_grads, first) -> list[bool]:
     """Tensors whose first gradient norm the stored weights resolve.
 
     A trust-ratio update ``gamma (g + wd w)`` has a length fixed by the
@@ -216,36 +221,36 @@ def resolvable(arch, hp, ref_grads, first) -> list[bool]:
     amplifies the float32 rounding of the weights by about
     ``|g| / (wd |w|)``. Where that exceeds ``MAX_AMPLIFICATION`` (on the
     reference's gradient) the norm is not read; a plain tensor's update
-    is ``base * g`` and is always read."""
+    is ``base * g`` and is always read; a held tensor has no update."""
     out = []
-    for (_, shape, _), g, (_, _, w2) in zip(reference.leaf_table(arch),
-                                            ref_grads, first):
+    for role, g, (_, _, w2) in zip(roles, ref_grads, first):
         wd_w = hp.wd * w2 ** 0.5
-        out.append(len(shape) < 2 or g <= MAX_AMPLIFICATION * wd_w)
+        out.append(role == "plain" or
+                   (role == "adapt" and g <= MAX_AMPLIFICATION * wd_w))
     return out
 
 
-def compare(config, traffic, arch, hp, key, losses, first,
-            moved) -> common.Check:
+def compare(config, traffic, hp, key, losses, first, moved) -> common.Check:
     rows = traffic["accum_steps"] * traffic["microbatch"] \
         * traffic["data_parallel"]
     S = traffic["seq_len"]
     ref = reference.train_readings(
-        arch, config["init"], hp, key, reference_rows(arch, key, rows, S),
-        S, len(losses))
-    return numbers(traffic, arch, hp, losses, first, moved, ref)
+        config, hp, key, reference_rows(config, key, rows, S), S,
+        len(losses))
+    return numbers(traffic, config, hp, losses, first, moved, ref)
 
 
-def numbers(traffic, arch, hp, losses, first, moved, ref) -> common.Check:
+def numbers(traffic, config, hp, losses, first, moved, ref) -> common.Check:
     lim = traffic["limits"]
     check = common.Check()
     loss_gap = max(abs(a - b) / abs(b) for a, b in zip(losses,
                                                        ref["losses"]))
-    keep = kept_leaves(ref["grad_norms"])
+    roles = arch.roles(config)
+    keep = kept_leaves(roles, ref["grad_norms"])
     readable = [k and r for k, r in zip(
-        keep, resolvable(arch, hp, ref["grad_norms"], first))]
+        keep, resolvable(roles, hp, ref["grad_norms"], first))]
     grad_gap, _ = reference.worst_gap(ref["grad_norms"],
-                                      program_grad_norms(arch, hp, first),
+                                      program_grad_norms(config, hp, first),
                                       readable)
     move_gap, _ = reference.worst_gap(ref["delta_norms"], moved, keep)
     check.number("loss_gap", loss_gap, lim["loss_gap"])
