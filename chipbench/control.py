@@ -8,7 +8,8 @@ Training cells: the plain reference in float32 against, in the
 program's place, (a) the reference computed in float8 (every matrix
 product's operands rounded to e4m3, the step below the bfloat16 the
 configuration computes in), (b) the reference that leaves out half of
-each step's rows and takes the mean over the rest, and, on a cell that
+each step's rows (of a step of one row, half of its tokens) and takes
+the mean over the rest, and, on a cell that
 spans chips, (c) the reference that keeps only the first device's rows,
 as each device does when the gradient exchange is left out. A step
 that returns its state unchanged reads 1 on ``update_norm_gap`` by its
@@ -17,7 +18,8 @@ definition and needs no run.
 Serving cells: a run of the cell (its window at the cell's own load),
 then, over the requests its check samples, the program's widest logit
 gap and the control's: at each served position the token the float8
-reference puts first, read against the float32 reference.
+reference puts first, read against the float32 reference. Both go
+through the cell's own comparison, each with its ``correct``.
 
 Each reading is one JSON line. The benchmark's own runs never call
 this.
@@ -44,6 +46,11 @@ def train_control(config: dict, traffic: dict, seeds) -> None:
     variants = {"fp8": ("fp8", None)}
     if rows > 1:
         variants["half_batch"] = ("f32", lambda i, r: r[: r.shape[0] // 2])
+    else:
+        # one row a step: half of its tokens left out, the mean taken
+        # over the rest
+        variants["half_batch"] = ("f32",
+                                  lambda i, r: r[:, : r.shape[1] // 2 + 1])
     if D > 1:
         # microbatch k holds rows [k*D*mb, (k+1)*D*mb); device 0 holds the
         # first mb of each
@@ -64,6 +71,7 @@ def train_control(config: dict, traffic: dict, seeds) -> None:
             check = train.numbers(traffic, config, hp, got["losses"],
                                   got["first"], got["delta_norms"], ref)
             print(json.dumps({"seed": seed, "variant": name,
+                              "correct": check.correct,
                               "readings": check.report()}), flush=True)
 
 
@@ -81,9 +89,13 @@ def serve_control(config: dict, traffic: dict, seeds, seconds: float,
         result, check, _ = serve.run(config, traffic, seed, seconds, False,
                                      devices, time.perf_counter(),
                                      after=also)
+        control = common.Check()
+        control.number("logit_gap", max(found["control"]),
+                       traffic["limits"]["logit_gap"])
         print(json.dumps({"seed": seed, "correct": check.correct,
                           "readings": check.report(),
-                          "control_logit_gap": max(found["control"]),
+                          "control_correct": control.correct,
+                          "control_readings": control.report(),
                           "control_per_request": found["control"],
                           "metrics": result["metrics"]}), flush=True)
 

@@ -22,6 +22,11 @@ sizes come from the configuration's architecture module
   position and every decoded token.
 * A decode step's attention needs each active slot's live K/V context
   read once, its query read and its output written, per layer.
+* A whole decode step needs every stored weight read once at its
+  storage width (the tied head once), each active slot's live K/V
+  context read, and the K/V of its new token written. Activations,
+  logits and the inactive slots of the pool are left out: the count is
+  the least the step must move.
 """
 from __future__ import annotations
 
@@ -63,3 +68,16 @@ def decode_attention_bytes(config: dict, contexts, itemsize: int = 2) -> int:
     m = arch.load(config)
     kv = sum(contexts) * m.kv_bytes_per_token(config, itemsize)
     return kv + len(contexts) * m.decode_io_bytes(config, itemsize)
+
+
+def decode_step_bytes(config: dict, contexts) -> int:
+    """One decode step of the whole model over the active slots, whose
+    ``contexts`` count the positions each attends over (its new token
+    included): the weights at the configuration's ``param_dtype``, the
+    live K/V read and the new K/V written at its ``cache_dtype``."""
+    import jax.numpy as jnp
+    m = arch.load(config)
+    w = jnp.dtype(config["program"]["param_dtype"]).itemsize
+    kv = m.kv_bytes_per_token(
+        config, jnp.dtype(config["serve"]["cache_dtype"]).itemsize)
+    return m.param_count(config) * w + (sum(contexts) + len(contexts)) * kv

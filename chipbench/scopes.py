@@ -18,6 +18,10 @@ under them on the trace's own clock.
 
 With a program that names no scopes (no ``repro.obs.scopes``) the
 reduction returns None and its readers report nothing.
+
+``executions`` needs no scopes: it times whole executions of one module
+found on the ``XLA Modules`` line, such as the serving engine's decode
+step.
 """
 from __future__ import annotations
 
@@ -34,6 +38,8 @@ METADATA_PLANE = "/host:metadata"
 HLO_PROTO_STAT = "Hlo Proto"
 # the executions of the jitted step ``trainer.fit`` runs
 STEP_MODULE = r"^jit_train_step\("
+# the executions of the serving engine's jitted decode step
+DECODE_MODULE = r"^jit__decode_fn\("
 # ops whose interval holds the ops of their bodies
 CONTROL_OPCODES = ("while", "conditional", "call")
 # a TPU trace names a Pallas call after its enclosing function; its op
@@ -293,6 +299,30 @@ def pallas_calls(run: dict, names) -> Optional[tuple[int, float]]:
             count += 1
             ns += op.end - op.start
     return count, ns / max(len(run["trace"].devices), 1) / 1e9
+
+
+def executions(run: dict, pattern: str) -> Optional[tuple[float, float]]:
+    """(executions, device seconds), each averaged over the devices, of
+    the modules whose name matches ``pattern`` and whose execution lies
+    wholly inside the traced window: the union of the ops that start
+    inside each execution, clipped to it. None where none ran."""
+    if "module_runs" not in run:
+        run["module_runs"] = module_runs(trace_file(run["logdir"]))
+    rx = re.compile(pattern)
+    trace = run["trace"]
+    lo, hi = trace.window
+    count = ns = 0
+    for dev, ops in trace.devices.items():
+        for s, e, name in run["module_runs"].get(dev, []):
+            if not (rx.match(name) and lo <= s and e <= hi):
+                continue
+            count += 1
+            ns += xplane.covered((o.start, min(o.end, e)) for o in ops
+                                 if s <= o.start < e)
+    if not count:
+        return None
+    k = len(trace.devices)
+    return count / k, ns / k / 1e9
 
 
 # ----------------------------------------------------------- annotations

@@ -1,26 +1,31 @@
 """Serving cells: open-loop traffic through ``serving.Engine``.
 
 The traffic file fixes the rate and the length distributions; a fixed
-``shape_seed`` draws the multiset of inter-arrival gaps and of (prompt,
-output) lengths, so every run serves the same work, and ``--seed``
-orders them and draws the token ids. Set-up makes the weights on the
-device from the seed in one call, builds the engine and compiles every
-prefill bucket the lengths can reach plus the one decode program, by
-serving short requests of those shapes.
+``shape_seed`` draws the inter-arrival gaps and the (prompt, output)
+lengths in their order, so every run serves the same work on the same
+schedule, and ``--seed`` draws the token ids. (Ordering them by the
+seed changed the work: in a window not much longer than a request, the
+order decides how many tokens are due before the close.) Set-up makes
+the weights on the device from the seed in one call, builds the engine
+and compiles every prefill bucket the lengths can reach plus the one
+decode program, by serving short requests of those shapes.
 
-The window submits each request when it is due and steps the engine
-whenever it holds work. Token times are taken from outside, through the
-public API: admission is first-in first-out, so the drop in
+The engine serves the traffic for ``preload_seconds`` before the window
+opens (counted as set-up), so the window finds it as a long-running
+server would: slots, queue and K/V pool filled to the rate's steady
+state. The window submits each request when it is due and steps the
+engine whenever it holds work. Token times are taken from outside,
+through the public API: admission is first-in first-out, so the drop in
 ``queue_depth`` over a step says which requests were admitted (each
 gains its prefill token and its first decoded token in that step) and
 every request already active gains one token per step; the results a
 step returns must agree with that count.
 
 Once the window has closed, the engine is freed and the plain reference
-runs over a sample of the finished requests drawn from the seed, the
-longest among them: at every served position, how far the reference's
-logit of the served token lies below its best. The widest such gap is
-compared with its limit.
+runs over a sample, drawn from the seed, of the requests that finished
+inside the window, the longest among them: at every served position,
+how far the reference's logit of the served token lies below its best.
+The widest such gap is compared with its limit.
 """
 from __future__ import annotations
 
@@ -50,9 +55,6 @@ def schedule(traffic: dict, seed: int, seconds: float, vocab: int):
     plens, olens = lengths(traffic["prompt_len"]), \
         lengths(traffic["output_len"])
     rng = np.random.default_rng(seed)
-    gaps = gaps[rng.permutation(n)]
-    order = rng.permutation(n)
-    plens, olens = plens[order], olens[order]
     prompts = [rng.integers(0, vocab, int(p), dtype=np.int32)
                for p in plens]
     return np.cumsum(gaps), prompts, [int(o) for o in olens]
@@ -188,9 +190,9 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
     if engine_hook is not None:
         engine_hook(eng)
 
-    length = traffic["trace_warm_seconds"] + traffic["trace_seconds"] \
-        if trace else seconds
-    due, prompts, outs = schedule(traffic, seed, length, vocab)
+    preload = traffic["preload_seconds"]
+    length = traffic["trace_seconds"] if trace else seconds
+    due, prompts, outs = schedule(traffic, seed, preload + length, vocab)
     reqs = [Req(d, p, o) for d, p, o in zip(due, prompts, outs)]
     logdir = os.path.join(common.TRACE_DIR, "serve")
     marks = {}
@@ -203,11 +205,11 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
         clock.mark_window()
         marks["t0"] = time.perf_counter()
 
-    setup_s = time.perf_counter() - t_start
     t0 = time.perf_counter()
-    close = t0 + length
+    opened, close = t0 + preload, t0 + preload + length
+    setup_s = opened - t_start
     out = serve_window(eng, reqs, t0, close, start_trace,
-                       t0 + traffic["trace_warm_seconds"] if trace else None)
+                       opened if trace else None)
     if trace:
         marks["ann"].__exit__(None, None, None)
         marks["t1"] = time.perf_counter()
@@ -219,13 +221,14 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
     del eng
     gc.collect()
 
-    due_in = [r for r in reqs if t0 + r.due < close]
+    due_in = [r for r in reqs if opened <= t0 + r.due < close]
     metrics, reader = {}, None
     if not trace:
         ttft = [((r.first if r.first is not None and r.first <= close
                   else close) - (t0 + r.due)) * 1e3 for r in due_in]
-        itl = [g * 1e3 for t, g in out["gaps"] if t <= close]
-        toks = sum(s["tokens"] for s in out["steps"] if s["end"] <= close)
+        itl = [g * 1e3 for t, g in out["gaps"] if opened < t <= close]
+        toks = sum(s["tokens"] for s in out["steps"]
+                   if opened < s["end"] <= close)
         metrics = {
             "serve_ttft_p95_ms": {"value": common.percentile(ttft, 95),
                                   "unit": "ms"},
@@ -245,6 +248,9 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
                   "records": records, "steps": len(traced), "flops": fl,
                   "decode_bytes": sum(flops.decode_attention_bytes(
                       config, s["contexts"]) for s in traced),
+                  "decode_steps": len(traced),
+                  "decode_step_bytes": sum(flops.decode_step_bytes(
+                      config, s["contexts"]) for s in traced),
                   "arrival_lag_s": [r.submit - (t0 + r.due) for r in reqs
                                     if r.submit is not None
                                     and lo <= r.submit <= hi],
@@ -254,10 +260,11 @@ def run(config: dict, traffic: dict, seed: int, seconds: float,
     for f in out["faults"]:
         check.require(False, f)
     check.require(not recompiled, "the engine compiled inside the window")
-    check.require(bool(out["finished"]), "no request finished")
+    finished = [r for r in out["finished"] if r.last > opened]
+    check.require(bool(finished), "no request finished in the window")
     failed = 0
-    if out["finished"]:
-        sampled = sample(out["finished"], traffic, seed)
+    if finished:
+        sampled = sample(finished, traffic, seed)
         gaps = logit_gaps(config, traffic, params, sampled)
         failed = sum(g > traffic["limits"]["logit_gap"] for g in gaps)
         check.number("logit_gap", max(gaps), traffic["limits"]["logit_gap"])

@@ -1,10 +1,17 @@
 #!/usr/bin/env python3
 """Find the knee of a serving cell once: one engine, set up once, serves
 the cell's traffic at each given rate for ``--seconds``; for each rate
-it prints the requests due, finished and still waiting at the close and
-the output tokens per second. The knee is the highest rate at which the
-waiting queue does not grow over the window (two or fewer waiting at
-the close); ``--set-rate 0.8`` writes 0.8 of it into the traffic file.
+it serves the cell's ``preload_seconds`` first, as a run does, and
+prints for the window that follows the requests due and finished, those
+waiting at its open and at its close, and the output tokens per second
+against those the traffic asks for. Give rates well past the knee: the
+most tokens per second that any rate reads is taken as the engine's
+saturated throughput. The knee is the highest rate that the engine
+holds with room to spare: two or fewer waiting at the close, and under
+0.9 of that throughput served. (The queue at the close alone is one
+sample: past saturation it can read empty while the p95 wait is
+seconds.) ``--set-rate 0.8`` writes 0.8 of the knee into the traffic
+file.
 
     python3 chipbench/sweep.py --workload <serve cell> --seed <n> \\
         --seconds 30 --rates 6 9 12 15
@@ -45,35 +52,42 @@ def main() -> int:
     t = time.perf_counter()
     serve.warm_up(eng, traffic, vocab)
     print(f"warm-up {time.perf_counter() - t:.1f} s", flush=True)
-    sustained = []
+    preload = traffic["preload_seconds"]
+    read = []
     for rate in args.rates:
         tr = dict(traffic, rate_per_s=rate)
-        due, prompts, outs = serve.schedule(tr, args.seed, args.seconds,
-                                            vocab)
+        due, prompts, outs = serve.schedule(tr, args.seed,
+                                            preload + args.seconds, vocab)
         reqs = [serve.Req(d, p, o) for d, p, o in zip(due, prompts, outs)]
         t0 = time.perf_counter()
-        close = t0 + args.seconds
-        out = serve.serve_window(eng, reqs, t0, close)
-        half = [r for r in reqs if r.first is None and t0 + r.due < close]
-        toks = sum(s["tokens"] for s in out["steps"] if s["end"] <= close)
+        opened, close = t0 + preload, t0 + preload + args.seconds
+        waiting = {}
+        out = serve.serve_window(
+            eng, reqs, t0, close,
+            lambda: waiting.setdefault("open", eng.queue_depth), opened)
+        due_in = [r for r in reqs if opened <= t0 + r.due < close]
+        toks = sum(s["tokens"] for s in out["steps"]
+                   if opened < s["end"] <= close)
         ttft = [((r.first if r.first is not None else close)
-                 - (t0 + r.due)) * 1e3 for r in reqs if t0 + r.due < close]
-        early = [x for r, x in zip(reqs, ttft) if r.due < args.seconds / 2]
-        late = [x for r, x in zip(reqs, ttft) if r.due >= args.seconds / 2]
+                 - (t0 + r.due)) * 1e3 for r in due_in]
         print(json.dumps({
-            "rate": rate, "due": len(ttft),
-            "finished": len(out["finished"]),
+            "rate": rate, "due": len(due_in),
+            "finished": sum(r.last > opened for r in out["finished"]),
+            "waiting_at_open": waiting.get("open"),
             "waiting_at_close": eng.queue_depth,
-            "unserved_at_close": len(half),
+            "active_at_close": len(out["open"]) - eng.queue_depth,
             "tokens_per_s": toks / args.seconds,
-            "ttft_p95_first_half_ms": common.percentile(early, 95),
-            "ttft_p95_second_half_ms": common.percentile(late, 95),
-            "steps": len(out["steps"])}), flush=True)
-        if eng.queue_depth <= 2:
-            sustained.append(rate)
+            "asked_tokens_per_s": sum(r.out for r in due_in) / args.seconds,
+            "ttft_p95_ms": common.percentile(ttft, 95),
+            "steps": sum(opened < s["end"] <= close
+                         for s in out["steps"])}), flush=True)
+        read.append((rate, eng.queue_depth, toks / args.seconds))
         for rid in out["open"]:
             eng.evict(rid)
-    knee = max(sustained) if sustained else None
+    saturated = max(t for _, _, t in read)
+    held = [r for r, waiting, t in read
+            if waiting <= 2 and t < 0.9 * saturated]
+    knee = max(held) if held else None
     print(json.dumps({"knee": knee, "device": common.device_info(devices)}))
     if args.set_rate and knee:
         path = os.path.join(common.HERE, "workloads",

@@ -6,7 +6,8 @@ cells' own limits.
     python3 -m pytest chipbench/tests -q      # by hand; not in tier-1
 
 Faults planted, one per test: a train step that returns its state
-unchanged; half of the batch left out, the mean taken over the rest;
+unchanged; half of the batch left out, the mean taken over the rest
+(of a step of one row, half of its tokens);
 the gradient exchange between four devices left out (each device keeps
 its own gradients); a served token altered where it is produced.
 """
@@ -57,19 +58,20 @@ def test_train_sound_run_is_correct():
     assert check.correct, check.report()
 
 
-def test_train_state_unchanged_is_not_correct():
+def unchanged_factory(model, opt, accum_steps, mesh):
+    """A step that returns its state unchanged but for the counter."""
     from repro.training import trainer
+    real = trainer.make_train_step(model, opt, accum_steps=accum_steps,
+                                   mesh=mesh)
 
-    def factory(model, opt, accum_steps, mesh):
-        real = trainer.make_train_step(model, opt, accum_steps=accum_steps,
-                                       mesh=mesh)
+    def step(state, batch):
+        _, metrics = real(state, batch)
+        return state._replace(step=state.step + 1), metrics
+    return step
 
-        def step(state, batch):
-            _, metrics = real(state, batch)
-            return state._replace(step=state.step + 1), metrics
-        return step
 
-    check = run_train(*train_cell("accum8"), step_factory=factory)
+def test_train_state_unchanged_is_not_correct():
+    check = run_train(*train_cell("accum8"), step_factory=unchanged_factory)
     assert not check.correct
     assert check.items["update_norm_gap"]["value"] == pytest.approx(1.0)
 
@@ -88,6 +90,47 @@ def test_train_half_batch_is_not_correct():
         return step
 
     check = run_train(*train_cell("accum8"), step_factory=factory)
+    assert not check.correct, check.report()
+
+
+def one_row_cell():
+    config, tr = train_cell("accum1", accum=1)
+    # a step of one 64-token row moves the 64 final-norm scales by about
+    # 1e-5 each; read back from float32 weights near 15 (an ulp of 1e-6)
+    # their change is resolved to about 0.4%, above update_norm_gap's
+    # limit, where near 1 it is resolved to 0.04% (program and
+    # reference gradients agree to 3e-7 here)
+    config["init"]["final_norm_mean"] = 1.0
+    return config, tr
+
+
+def test_train_one_row_sound_run_is_correct():
+    check = run_train(*one_row_cell())
+    assert check.correct, check.report()
+
+
+def test_train_one_row_state_unchanged_is_not_correct():
+    check = run_train(*one_row_cell(), step_factory=unchanged_factory)
+    assert not check.correct
+    assert check.items["update_norm_gap"]["value"] == pytest.approx(1.0)
+
+
+def test_train_one_row_half_tokens_is_not_correct():
+    """A step of one row has no half batch of rows: the fault leaves
+    out the second half of the row's tokens and takes the mean over
+    the rest."""
+    from repro.training import trainer
+
+    def factory(model, opt, accum_steps, mesh):
+        real = trainer.make_train_step(model, opt, accum_steps=accum_steps,
+                                       mesh=mesh)
+
+        def step(state, batch):
+            return real(state, {k: v[:, : v.shape[1] // 2]
+                                for k, v in batch.items()})
+        return step
+
+    check = run_train(*one_row_cell(), step_factory=factory)
     assert not check.correct, check.report()
 
 
@@ -127,8 +170,9 @@ def serve_cell():
     config.update(TINY)
     config["serve"].update(slots=4, max_len=128, prefill_batch=2)
     tr = load("workloads", "chat-poisson")
-    tr.update(rate_per_s=4.0, warm_prefill_lengths=[16, 32, 64],
-              warm_prefill_batches=[1, 2], sample_requests=4)
+    tr.update(warm_prefill_lengths=[16, 32, 64],
+              warm_prefill_batches=[1, 2], sample_requests=4,
+              preload_seconds=1.0)
     tr["prompt_len"].update(median=24, min=16, max=64)
     tr["output_len"].update(median=12, min=4, max=32)
     return config, tr

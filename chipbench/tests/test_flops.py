@@ -66,3 +66,17 @@ def test_decode_and_prefill_flops():
     body = 2 * 36 * 77_070_336 * 4
     attn = 4 * 36 * 2048 * (1 + 2 + 3 + 4)
     assert flops.prefill_flops(s, 4) == body + attn + 2 * 311_164_928
+
+
+def test_decode_step_bytes():
+    s = shape("qwen2.5-3b-serve")
+    # every bf16 weight once: 6.17 GB
+    weights = 3_085_938_688 * 2
+    assert weights == 6_171_877_376
+    # two slots attending over 100 and 300 positions read that K/V and
+    # write one new token's K/V each, at 36,864 B a token
+    assert flops.decode_step_bytes(s, [100, 300]) == \
+        weights + (400 + 2) * 36_864
+    # 32 slots at 690 positions: the 7.0 GB a step of the serving cell
+    assert abs(flops.decode_step_bytes(s, [690] * 32) / 1e9 - 6.99) < 0.01
+    assert flops.decode_step_bytes(s, []) == weights

@@ -259,3 +259,57 @@ def test_update_readers_count_only_the_optimizer_kernels():
     run = _kernel_run(ops[3:])
     assert common.read_metric("segmented_update.kernel_ms", run) is None
     assert common.read_metric("segmented_update.hbm_roofline", run) is None
+
+
+# a serving window: two decode executions inside it, a prefill between
+# them, and a third decode cut by the window's end
+DECODE = "jit__decode_fn(4242)"
+
+
+def _serve_run():
+    ops = [op(2, 10, "while.1"),          # the decode's layer loop ...
+           op(4, 9, "fusion.2"),          # ... and an op of its body
+           op(12, 18, "attention_decode.3"),
+           op(20, 29, "fusion.7"),        # the prefill's
+           op(30, 45, "fusion.2"),
+           op(47, 60, "copy.1"),          # no module: host-driven insert
+           op(96, 100, "while.1")]
+    runs = {d: [(2, 20, DECODE), (20, 30, "jit__prefill_fn(77)"),
+                (30, 46, DECODE), (96, 104, DECODE)] for d in (0, 1)}
+    return {"kind": "serve", "trace": xplane.Trace((0, 100),
+                                                   {0: ops, 1: ops}),
+            "module_runs": runs, "decode_steps": 2,
+            "decode_step_bytes": 2 * 7.25,
+            "peaks": {"hbm_bytes_per_s": 1e9},
+            "spans": [("decode", 1, 3), ("sample", 3, 19),
+                      ("prefill", 19, 29), ("decode", 29, 31),
+                      ("sample", 31, 46), ("decode", 98, 102)]}
+
+
+def test_decode_executions_count_only_the_decode_module():
+    run = _serve_run()
+    # (8 + 6) ns in the first execution, 15 in the second, per device;
+    # the prefill's op, the insert and the cut execution are not counted
+    assert scopes.executions(run, scopes.DECODE_MODULE) == \
+        (2, pytest.approx(29e-9))
+    assert common.read_metric("serve.decode_step_ms", run) == \
+        pytest.approx(14.5e-6)
+    # 7.25 bytes a step at 1 GB/s take 7.25 ns; a step takes 14.5 ns
+    assert common.read_metric("serve.decode_hbm_roofline", run) == \
+        pytest.approx(50.0)
+    # decode + sample spans inside the window, per decode span
+    assert common.read_metric("serve.decode_span_ms", run) == \
+        pytest.approx((2 + 16 + 2 + 15) / 2 / 1e6)
+
+
+def test_decode_readers_report_nothing_without_a_decode():
+    run = _serve_run()
+    run["module_runs"] = {d: [(20, 30, "jit__prefill_fn(77)")]
+                          for d in (0, 1)}
+    run["spans"] = []
+    for name in ("serve.decode_step_ms", "serve.decode_hbm_roofline",
+                 "serve.decode_span_ms"):
+        assert common.read_metric(name, run) is None
+    # nor do they read a training run
+    assert common.read_metric("serve.decode_step_ms", {"kind": "train"}) \
+        is None
