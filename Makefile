@@ -1,5 +1,5 @@
 .PHONY: check test fast bench bench-pipeline overlap obs serving \
-	serve-kernel serve-bench smoke lint multidevice
+	serve-kernel smoke lint multidevice
 
 # tier-1 suite + REPRO_FORCE_REF=1 oracle re-run (both dispatch modes)
 # + e2e launcher smoke with gradient accumulation (K>1) + probe smoke
@@ -61,14 +61,6 @@ serve-kernel:
 	    -k "kernel or bf16_cache or wraparound"
 	REPRO_FORCE_REF=1 PYTHONPATH=src python -m pytest -q \
 	    tests/test_serving.py -k "kernel or bf16_cache"
-
-# serving engine bench: saturated continuous batching vs sequential
-# per-request generate (>=1.5x tokens/sec floor), prefill/decode phase
-# split from engine trace spans, fused decode-kernel sweep (>=1.15x
-# decode floor, asserted on tpu/gpu only) + open-loop Poisson latency
-# percentiles; writes BENCH_serve.json
-serve-bench:
-	PYTHONPATH=src:. python benchmarks/bench_serve.py
 
 # end-to-end CPU smoke of the launcher: global batch 8 = 4 accumulated
 # microbatches of 2, optimizer applied once per global step — then the

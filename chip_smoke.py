@@ -393,8 +393,8 @@ def phase_decode_kernel(facts: dict, *, cfg,
     q = jax.random.normal(ks[0], (b, 1, h, dh)).astype(bf16)
     nk = jax.random.normal(ks[1], (b, 1, hkv, dh)).astype(bf16)
     nv = jax.random.normal(ks[2], (b, 1, hkv, dh)).astype(bf16)
-    kc = jax.random.normal(ks[3], (b, max_len, hkv, dh)).astype(bf16)
-    vc = jax.random.normal(ks[4], (b, max_len, hkv, dh)).astype(bf16)
+    kc = jax.random.normal(ks[3], (b, max_len, hkv * dh)).astype(bf16)
+    vc = jax.random.normal(ks[4], (b, max_len, hkv * dh)).astype(bf16)
     pos = jax.random.randint(ks[5], (b,), 0, max_len)
     out, kc_k, vc_k = jax.jit(ops.attention_decode_fused)(q, nk, nv, kc, vc,
                                                           pos)

@@ -7,7 +7,8 @@
 #   lars_update.py        per-tensor fused LARS step (2 pallas_calls/leaf)
 #   segmented_update.py   whole-tree segmented step  (2 pallas_calls/step)
 #   rmsnorm.py            fused RMSNorm (activation-path exemplar)
-#   attention_decode.py   fused serving decode: KV ring append +
-#                         mask-from-pos + online-softmax GQA (1 call/layer)
+#   attention_decode.py   fused serving decode: reads only the live K/V
+#                         of a merged-head pool, mask-from-pos +
+#                         online-softmax GQA (1 call/layer)
 #   ref.py                pure-jnp oracles + shared layer-wise math
 #   ops.py                dispatch (TPU native / interpret / REPRO_FORCE_REF)

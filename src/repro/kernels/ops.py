@@ -80,7 +80,7 @@ def attention_decode_fused(q, new_k, new_v, k_cache, v_cache, pos, *,
     """Fused serving-decode attention: per-row KV ring append +
     mask-from-``pos`` + online-softmax GQA contraction in one launch.
     q [B,1,H,Dh], new_k/new_v [B,1,Hkv,Dh] (rope'd), caches
-    [B,T,Hkv,Dh], pos [B] int32 -> (out, new_k_cache, new_v_cache);
+    [B,T,Hkv·Dh], pos [B] int32 -> (out, new_k_cache, new_v_cache);
     see ``kernels.ref.decode_parity_tolerance`` for the parity model.
     """
     if _force_ref():

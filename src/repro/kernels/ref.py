@@ -348,20 +348,22 @@ def ref_attention_decode(q, new_k, new_v, k_cache, v_cache, pos, *,
                          window=None):
     """Pure-jnp oracle for the fused decode step, same operand layout
     as ``attention_decode_pallas``: q [B,1,H,Dh], new_k/new_v
-    [B,1,Hkv,Dh] (both already rope'd), caches [B,T,Hkv,Dh], pos [B]
-    int32 per-row depths. Per-row ring append at ``pos % T`` (windowed)
-    or ``pos`` (global), validity mask derived from ``pos``, grouped
-    contraction with f32 scores/softmax/accumulation. Returns
-    (out [B,1,H,Dh], new_k_cache, new_v_cache).
+    [B,1,Hkv,Dh] (both already rope'd), caches [B,T,Hkv·Dh] (heads
+    merged), pos [B] int32 per-row depths. Per-row ring append at
+    ``pos % T`` (windowed) or ``pos`` (global), validity mask derived
+    from ``pos``, grouped contraction with f32 scores/softmax/
+    accumulation. Returns (out [B,1,H,Dh], new_k_cache, new_v_cache).
     """
     b, _, h, dh = q.shape
-    t, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv = new_k.shape[2]
+    t = k_cache.shape[1]
     pos = jnp.asarray(pos, jnp.int32)
     slot = pos % t if window is not None else pos
 
     def write(cache, new):
         return jax.vmap(lambda c, n, s: jax.lax.dynamic_update_slice_in_dim(
-            c, n, s, axis=0))(cache, new.astype(cache.dtype), slot)
+            c, n, s, axis=0))(cache, new.reshape(b, 1, hkv * dh).astype(
+                cache.dtype), slot)
 
     kc, vc = write(k_cache, new_k), write(v_cache, new_v)
     kpos = jnp.arange(t)[None, :]                      # [1,T]
@@ -373,10 +375,11 @@ def ref_attention_decode(q, new_k, new_v, k_cache, v_cache, pos, *,
             & (abs_pos > pos_c - window)
     else:
         ok = kpos <= pos_c                             # [B,T]
+    k4 = kc.reshape(b, t, hkv, dh).astype(jnp.float32)
+    v4 = vc.reshape(b, t, hkv, dh).astype(jnp.float32)
     qg = q.astype(jnp.float32).reshape(b, hkv, h // hkv, dh)
-    s = jnp.einsum("bkgd,btkd->bkgt", qg, kc.astype(jnp.float32)) \
-        / math.sqrt(dh)
+    s = jnp.einsum("bkgd,btkd->bkgt", qg, k4) / math.sqrt(dh)
     s = jnp.where(ok[:, None, None, :], s, NEG_INF)
     probs = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgt,btkd->bkgd", probs, vc.astype(jnp.float32))
+    out = jnp.einsum("bkgt,btkd->bkgd", probs, v4)
     return out.reshape(b, 1, h, dh).astype(q.dtype), kc, vc

@@ -143,11 +143,13 @@ def batch_pspecs(mesh: Mesh, batch_shapes: dict) -> dict:
 def cache_pspecs(mesh: Mesh, cache_shapes: Any) -> Any:
     """KV/SSM cache sharding for decode.
 
-    Layout conventions (see models/*): attention caches are
-    [layers, B, T, Hkv, Dh] (k/v/ck/cv); SSM state [.., B, H, P, N] and
+    Layout conventions (see models/*): self-attention pools are
+    [layers, B, T, Hkv·Dh] (k/v, heads merged), cross-attention K/V
+    [layers, B, T, Hkv, Dh] (ck/cv); SSM state [.., B, H, P, N] and
     conv [.., B, W-1, C]. Batch shards over (pod,data). The model axis
     goes to Hkv when it divides, else to the sequence dim T (long-context
-    global layers), else stays replicated.
+    global layers), else to Dh; a merged pool, whose head boundaries its
+    shape does not show, takes it on T, else on Hkv·Dh.
     """
     m = mesh.shape.get("model", 1)
     dp = _data_axes(mesh)
@@ -165,7 +167,15 @@ def cache_pspecs(mesh: Mesh, cache_shapes: Any) -> Any:
         if nd == 0:
             return P()
         out: list = [None] * nd
-        if names[-1] in ("k", "v", "ck", "cv"):
+        if names[-1] in ("k", "v"):       # [..., B, T, Hkv·Dh]
+            b_dim, t_dim, h_dim = nd - 3, nd - 2, nd - 1
+            shard_b(out, leaf, b_dim)
+            for dim in (t_dim, h_dim):
+                if m > 1 and leaf.shape[dim] % m == 0 and leaf.shape[dim] >= m:
+                    out[dim] = "model"
+                    break
+            return P(*out)
+        if names[-1] in ("ck", "cv"):
             # [..., B, T, Hkv, Dh]: model axis -> Hkv, else T, else Dh
             # (whisper: 20 heads and T=1500 both indivisible by 16, but
             # Dh=64 shards — partial scores + all-reduce over Dh).

@@ -108,8 +108,8 @@ def init_encdec_cache(cfg: ModelConfig, params: dict, batch: int,
     hkv, hd = cfg.num_kv_heads, cfg.head_dim_
     ldec = cfg.num_layers
     return {
-        "k": jnp.zeros((ldec, batch, max_len, hkv, hd), cfg.cdtype),
-        "v": jnp.zeros((ldec, batch, max_len, hkv, hd), cfg.cdtype),
+        "k": jnp.zeros((ldec, batch, max_len, hkv * hd), cfg.cdtype),
+        "v": jnp.zeros((ldec, batch, max_len, hkv * hd), cfg.cdtype),
         "ck": ck, "cv": cv,
     }
 

@@ -174,8 +174,8 @@ def init_hybrid_cache(cfg: ModelConfig, params: dict, batch: int,
             lambda x: jnp.broadcast_to(
                 x[None, None], (groups, cfg.attn_every) + x.shape).copy(),
             single),
-        "k": jnp.zeros((groups, batch, max_len, hkv, hd), cfg.cdtype),
-        "v": jnp.zeros((groups, batch, max_len, hkv, hd), cfg.cdtype),
+        "k": jnp.zeros((groups, batch, max_len, hkv * hd), cfg.cdtype),
+        "v": jnp.zeros((groups, batch, max_len, hkv * hd), cfg.cdtype),
     }
     if rem:
         cache["ssm_trailing"] = jax.tree_util.tree_map(

@@ -350,9 +350,10 @@ def attention(params: dict, cfg: ModelConfig, x: jnp.ndarray,
     """Self-attention when kv_src is None, else cross-attention.
 
     ``return_kv=True`` additionally returns the (rope'd) K and V
-    [B,T,Hkv,Dh] — exactly the tensors ``attention_decode`` writes into
-    its cache, so a full-sequence forward can dump a decode-ready KV
-    cache (the serving engine's single-shot batched prefill)."""
+    [B,T,Hkv,Dh] — the tensors ``attention_decode`` writes into its
+    cache, heads merged, so a full-sequence forward can dump a
+    decode-ready KV cache (the serving engine's single-shot batched
+    prefill)."""
     cross = kv_src is not None
     kv_in = kv_src if cross else x
     q, k, v = _qkv(params, x, kv_in, cfg)
@@ -381,7 +382,8 @@ def attention_decode(params: dict, cfg: ModelConfig, x: jnp.ndarray,
                      pos: jnp.ndarray, *, window: Optional[int] = None,
                      use_rope: bool = True,
                      use_kernel: Optional[bool] = None):
-    """One-token decode. x: [B,1,D]; caches [B,T,Hkv,Dh]; pos: scalar
+    """One-token decode. x: [B,1,D]; caches [B,T,Hkv·Dh] (heads merged,
+    the layout the decode kernel reads densely); pos: scalar
     (all rows at the same depth — the training-era path) OR a [B] int32
     vector of per-row depths — the serving engine's continuous-batching
     path, where every slot of the decode batch is mid-way through a
@@ -395,8 +397,9 @@ def attention_decode(params: dict, cfg: ModelConfig, x: jnp.ndarray,
     ``use_kernel`` (default ``cfg.use_decode_kernel``) routes the
     cache write + mask + contraction through the fused Pallas decode
     kernel (``repro.kernels.ops.attention_decode_fused`` — one launch
-    per layer, KV read exactly once, f32 online softmax); projections
-    and RoPE stay here so kernel and jnp paths share them exactly.
+    per layer, only the live K/V read, once, f32 online softmax);
+    projections and RoPE stay here so kernel and jnp paths share them
+    exactly.
     """
     b = x.shape[0]
     t = k_cache.shape[1]
@@ -418,6 +421,8 @@ def attention_decode(params: dict, cfg: ModelConfig, x: jnp.ndarray,
                          params["wo"].astype(x.dtype))
         return out, k_cache, v_cache
     slot = pos % t if window is not None else pos
+    heads = (b, t) + k.shape[2:]          # [B,T,Hkv,Dh] view of the pool
+    k, v = k.reshape(b, 1, -1), v.reshape(b, 1, -1)
     if vec:
         k_cache = _write_row(k_cache, k, slot)
         v_cache = _write_row(v_cache, v, slot)
@@ -444,8 +449,8 @@ def attention_decode(params: dict, cfg: ModelConfig, x: jnp.ndarray,
     # scalar pos: ok is [T] -> [1,1,1,T]; vector pos: [B,T] -> [B,1,1,T]
     mask = jnp.where(ok, 0.0, NEG_INF)
     mask = mask[:, None, None, :] if vec else mask[None, None, None, :]
-    out = gqa_scores_apply(q, k_cache.astype(q.dtype),
-                           v_cache.astype(q.dtype), mask)
+    out = gqa_scores_apply(q, k_cache.reshape(heads).astype(q.dtype),
+                           v_cache.reshape(heads).astype(q.dtype), mask)
     out = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     return out, k_cache, v_cache
 

@@ -301,29 +301,31 @@ def init_lm_cache(cfg: ModelConfig, params: dict, batch: int, max_len: int,
         else:
             t = (min(cfg.sliding_window, max_len)
                  if kind == "local" and cfg.sliding_window else max_len)
-            cache[name] = {
-                "k": jnp.zeros((groups, batch, t, hkv, hd), dt),
-                "v": jnp.zeros((groups, batch, t, hkv, hd), dt)}
+            cache[name] = {             # heads merged: [G,B,T,Hkv·Dh]
+                "k": jnp.zeros((groups, batch, t, hkv * hd), dt),
+                "v": jnp.zeros((groups, batch, t, hkv * hd), dt)}
     return cache
 
 
 def _prefill_cache_layout(cfg: ModelConfig, kind: str, k: jnp.ndarray,
                           v: jnp.ndarray, max_len: int,
                           lens: Optional[jnp.ndarray] = None) -> dict:
-    """[G,B,S,...] prefill K/V -> the ``init_lm_cache`` layout at
-    ``max_len``: global layers zero-pad the sequence axis to T=max_len;
-    local (sliding-window) layers gather each ROW's last
-    ``min(lens[b], window)`` tokens into their ring slots (p % T_local)
-    — byte-identical to what streaming that row's prompt through
-    ``attention_decode`` leaves behind. ``lens`` [B] gives per-row
+    """[G,B,S,Hkv,Dh] prefill K/V -> the ``init_lm_cache`` layout
+    ``[G,B,T,Hkv·Dh]`` at ``max_len``: global layers zero-pad the
+    sequence axis to T=max_len; local (sliding-window) layers gather
+    each ROW's last ``min(lens[b], window)`` tokens into their ring
+    slots (p % T_local) — byte-identical to what streaming that row's
+    prompt through ``attention_decode`` leaves behind. ``lens`` [B] gives per-row
     prompt lengths for right-padded batches (None = every row is the
     full S); global layers need no masking because decode writes each
     new key at the row's depth BEFORE attending, so pad-position keys
     are overwritten or masked, never read."""
     g, b, s, hkv, hd = k.shape
-    k = k.astype(cfg.kv_dtype)   # prefill dump lands at pool storage
-    v = v.astype(cfg.kv_dtype)   # dtype (same rounding as decode's
-    if kind == "local" and cfg.sliding_window:   # cache-row writes)
+    # prefill dump lands at pool storage dtype (same rounding as
+    # decode's cache-row writes), heads merged
+    k = k.astype(cfg.kv_dtype).reshape(g, b, s, hkv * hd)
+    v = v.astype(cfg.kv_dtype).reshape(g, b, s, hkv * hd)
+    if kind == "local" and cfg.sliding_window:
         t = min(cfg.sliding_window, max_len)
         last = (jnp.full((b,), s, jnp.int32) if lens is None
                 else lens.astype(jnp.int32))[:, None] - 1   # [B,1]
@@ -331,14 +333,13 @@ def _prefill_cache_layout(cfg: ModelConfig, kind: str, k: jnp.ndarray,
         # p % t == q (exactly what decode's abs_pos arithmetic assumes)
         q = jnp.arange(t, dtype=jnp.int32)[None, :]         # [1,T]
         p = last - ((last - q) % t)                         # [B,T]
-        valid = (p >= 0)[None, :, :, None, None]
-        idx = jnp.clip(p, 0, s - 1)[None, :, :, None, None]
-        kc = jnp.where(valid, jnp.take_along_axis(
-            k, jnp.broadcast_to(idx, (g, b, t, 1, 1)), axis=2), 0)
-        vc = jnp.where(valid, jnp.take_along_axis(
-            v, jnp.broadcast_to(idx, (g, b, t, 1, 1)), axis=2), 0)
+        valid = (p >= 0)[None, :, :, None]
+        idx = jnp.broadcast_to(jnp.clip(p, 0, s - 1)[None, :, :, None],
+                               (g, b, t, 1))
+        kc = jnp.where(valid, jnp.take_along_axis(k, idx, axis=2), 0)
+        vc = jnp.where(valid, jnp.take_along_axis(v, idx, axis=2), 0)
         return {"k": kc, "v": vc}
-    pad = ((0, 0), (0, 0), (0, max_len - s), (0, 0), (0, 0))
+    pad = ((0, 0), (0, 0), (0, max_len - s), (0, 0))
     return {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)}
 
 

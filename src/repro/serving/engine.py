@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import attention_decode
 from repro.obs import trace
 from repro.serving import sampling
 from repro.serving.kv_cache import PagedKVCache
@@ -163,6 +164,17 @@ class Engine:
         # pow2 padding must stay within the extra-embeds rows
         self._prefill_cap = min(config.prefill_batch, config.slots)
         self._kv = PagedKVCache(model, params, config, extra)
+        # the decode kernel's K/V blocks: (layers, T, block_t) per
+        # self-attention pool [G, B, T, Hkv·Dh]; none on the jnp path
+        pools = jax.tree_util.tree_flatten_with_path(self._kv.cache)[0]
+        self._kv_plan = [
+            (k.shape[0], k.shape[2],
+             attention_decode.block_len(k.shape[2], k.shape[3], k.dtype))
+            for path, k in pools if getattr(path[-1], "key", None) == "k"
+        ] if model.cfg.use_decode_kernel else []
+        self._kv_pool_step = sum(g * config.slots * (t // bt)
+                                 for g, t, bt in self._kv_plan)
+        self._kv_blocks_read = self._kv_blocks_pool = 0
         self._pos = np.zeros(config.slots, np.int32)
         self._tok = np.zeros(config.slots, np.int32)
         self._active: list = [None] * config.slots
@@ -258,7 +270,10 @@ class Engine:
         ``decode`` is the async dispatch, ``sample`` the device sync
         that materializes the sampled tokens, ``finish`` the host
         bookkeeping) — ``launch/serve.py --trace-out`` exports them
-        through the run-wide trace tooling."""
+        through the run-wide trace tooling. ``decode`` carries
+        ``kv_blocks``: the K/V blocks the decode kernel reads in the
+        step, over every layer and slot (``stats()`` keeps the running
+        totals)."""
         tr = self.tracer
         with tr.span("admit", step=self._steps,
                      waiting=len(self._waiting)):
@@ -266,8 +281,13 @@ class Engine:
         if any(r is not None for r in self._active):
             tok = jnp.asarray(self._tok[:, None])
             pos = jnp.asarray(self._pos)
+            kv_blocks = sum(
+                g * int(attention_decode.live_blocks(self._pos, t, bt).sum())
+                for g, t, bt in self._kv_plan)
+            self._kv_blocks_read += kv_blocks
+            self._kv_blocks_pool += self._kv_pool_step
             with tr.span("decode", step=self._steps,
-                         active=self.active_count):
+                         active=self.active_count, kv_blocks=kv_blocks):
                 nxt, self._kv.cache = self._decode(
                     self.params, self._kv.cache, tok, pos,
                     self._fold_key())
@@ -406,4 +426,6 @@ class Engine:
                 "waiting": self.queue_depth,
                 "decode_compilations": self.decode_compilations,
                 "prefill_compilations": self.prefill_compilations,
+                "decode_kv_blocks_read": self._kv_blocks_read,
+                "decode_kv_blocks_pool": self._kv_blocks_pool,
                 **self._kv.table.stats()}

@@ -28,6 +28,7 @@ from repro.configs import get_smoke_config
 from repro.configs.base import ModelConfig
 from repro.kernels import ops as kernel_ops
 from repro.kernels import ref as kernel_ref
+from repro.kernels.attention_decode import attention_decode_pallas
 from repro.models import get_model, layers
 
 pytestmark = pytest.mark.serving
@@ -249,26 +250,44 @@ def _decode_operands(b, t, h, hkv, dh, cache_dtype, pos, seed=0):
     q = jnp.asarray(rng.randn(b, 1, h, dh), jnp.float32)
     nk = jnp.asarray(rng.randn(b, 1, hkv, dh), jnp.float32)
     nv = jnp.asarray(rng.randn(b, 1, hkv, dh), jnp.float32)
-    kc = jnp.asarray(rng.randn(b, t, hkv, dh)).astype(cache_dtype)
-    vc = jnp.asarray(rng.randn(b, t, hkv, dh)).astype(cache_dtype)
+    kc = jnp.asarray(rng.randn(b, t, hkv * dh)).astype(cache_dtype)
+    vc = jnp.asarray(rng.randn(b, t, hkv * dh)).astype(cache_dtype)
     return q, nk, nv, kc, vc, jnp.asarray(pos, jnp.int32)
 
 
+# rows of the 4-block cases: a free slot (pos 0), live prefixes ending
+# on a block's last row, on the next block's first row and mid-block,
+# pos T-1; the ring adds rows past the wrap (all of it live)
+_BLOCK_ROWS = [0, 15, 16, 37, 62, 63]
+_RING_ROWS = [0, 15, 37, 63, 64, 79, 100, 200]
+
+
 @pytest.mark.parametrize("h,hkv", [(4, 2), (4, 1), (2, 2)])
-@pytest.mark.parametrize("window", [None, 8])
+@pytest.mark.parametrize("window,t,block_t", [
+    (None, 32, None), (8, 8, None), (None, 64, 16), (64, 64, 16)],
+    ids=["None", "8", "None-4blocks", "64-4blocks"])
 @pytest.mark.parametrize("cache_dtype", ["float32", "bfloat16"])
-def test_decode_kernel_matches_oracle(h, hkv, window, cache_dtype):
+def test_decode_kernel_matches_oracle(h, hkv, window, t, block_t,
+                                      cache_dtype):
     """Op-level three-way parity across GQA/MQA/MHA layouts, global and
     ring-buffer layers, f32 and bf16 pools.  Windowed rows sit several
-    multiples past the window (deep wrap); caches must be bitwise
+    multiples past the window (deep wrap). The 4-block cases run the
+    live-block clamp: each row reads only up to its last live block,
+    and the ring reads all of it once wrapped. Caches must be bitwise
     identical (same single-row append), outputs within the documented
     tolerance."""
     dt = jnp.dtype(cache_dtype)
-    t = 8 if window else 32
-    pos = [0, 9, 30] if window else [0, 5, 31]
-    operands = _decode_operands(3, t, h, hkv, 16, dt, pos)
-    o1, k1, v1 = kernel_ops.attention_decode_fused(*operands,
-                                                   window=window)
+    if block_t:
+        pos = _RING_ROWS if window else _BLOCK_ROWS
+    else:
+        pos = [0, 9, 30] if window else [0, 5, 31]
+    operands = _decode_operands(len(pos), t, h, hkv, 16, dt, pos)
+    if block_t:
+        o1, k1, v1 = attention_decode_pallas(
+            *operands, window=window, interpret=True, _block_t=block_t)
+    else:
+        o1, k1, v1 = kernel_ops.attention_decode_fused(*operands,
+                                                       window=window)
     o2, k2, v2 = kernel_ref.ref_attention_decode(*operands,
                                                  window=window)
     tol = kernel_ref.decode_parity_tolerance(dt)
@@ -292,8 +311,8 @@ def test_layer_decode_kernel_matches_jnp():
              (8, 8, jnp.asarray([2, 29, 17], jnp.int32)),
              (8, 8, jnp.int32(19))]
     for window, t, pos in cases:
-        kc = jnp.asarray(rng.randn(3, t, 2, 16), jnp.float32)
-        vc = jnp.asarray(rng.randn(3, t, 2, 16), jnp.float32)
+        kc = jnp.asarray(rng.randn(3, t, 2 * 16), jnp.float32)
+        vc = jnp.asarray(rng.randn(3, t, 2 * 16), jnp.float32)
         o1, k1, v1 = layers.attention_decode(params, cfg, x, kc, vc,
                                              pos, window=window,
                                              use_kernel=True)
@@ -316,8 +335,8 @@ def test_bf16_cache_decode_accumulates_f32():
     params = layers.init_attention(cfg, jax.random.PRNGKey(2))
     rng = np.random.RandomState(3)
     x = jnp.asarray(rng.randn(2, 1, 64), jnp.float32)
-    kc = jnp.asarray(rng.randn(2, 32, 2, 16), jnp.float32)
-    vc = jnp.asarray(rng.randn(2, 32, 2, 16), jnp.float32)
+    kc = jnp.asarray(rng.randn(2, 32, 2 * 16), jnp.float32)
+    vc = jnp.asarray(rng.randn(2, 32, 2 * 16), jnp.float32)
     pos = jnp.asarray([12, 31], jnp.int32)
     tol = kernel_ref.decode_parity_tolerance(jnp.bfloat16)
     want, _, _ = layers.attention_decode(params, cfg, x, kc, vc, pos,
@@ -396,6 +415,44 @@ def test_engine_kernel_bf16_cache_matches_jnp():
         model, params, serving.ServeConfig(**kw, use_kernel=True)),
         prompts, new=12)
     assert got == want
+
+
+def test_engine_counts_live_kv_blocks(monkeypatch):
+    """``decode_kv_blocks_read`` sums, over decode steps, layers and
+    slots, the blocks the kernel reads (``min(pos, T-1) // block_t +
+    1``), and each ``decode`` span carries the step's count. A 2 KiB
+    tile budget cuts the 64-row global pools of 64 f32 lanes into 8
+    blocks of 8 rows; the 8-row rings stay one block. The tokens stay
+    those of the jnp ``generate``."""
+    from repro.kernels import attention_decode
+    from repro.obs.trace import Tracer
+    monkeypatch.setattr(attention_decode, "TILE_BYTES", 2048)
+    cfg, model, params = _model("gemma3-12b")
+    tracer = Tracer()
+    eng = serving.Engine(model, params, serving.ServeConfig(
+        slots=3, max_len=64, page_size=8, prefill_batch=4,
+        use_kernel=True), tracer=tracer)
+    lens, new = (5, 20, 11), (12, 30, 7)
+    prompts = _prompts(cfg, lens, seed=23)
+    ids = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
+    eng.drain()
+    for rid, p, n in zip(ids, prompts, new):
+        assert eng.result(rid).tokens == _reference(model, params, p, n, 64)
+    # slot s holds request s (admitted together at step 0); it decodes
+    # at depths lens[s] .. lens[s] + new[s] - 2, then sits free at 0
+    pools = [(2, 8, 8), (2, 64, 8)]       # (layers, T, block_t)
+    per_step = []
+    for k in range(max(new) - 1):
+        pos = [ln + k if k <= n - 2 else 0 for ln, n in zip(lens, new)]
+        per_step.append(sum(g * (min(p, t - 1) // bt + 1)
+                            for g, t, bt in pools for p in pos))
+    spans = [r["kv_blocks"] for r in tracer.events()
+             if r["kind"] == "span" and r["name"] == "decode"]
+    assert spans == per_step
+    stats = eng.stats()
+    assert stats["decode_kv_blocks_read"] == sum(per_step)
+    assert stats["decode_kv_blocks_pool"] == len(per_step) * 3 * (2 + 16)
+    assert eng.decode_compilations == 1
 
 
 def test_serve_config_rejects_bad_cache_dtype():

@@ -97,29 +97,38 @@ def test_segmented_update_compiles_for_v5e(one_chip, mode, dtype):
     assert _native(compiled)
 
 
-@pytest.mark.parametrize("heads,kv_heads,head_dim,length,window,dtype", [
+@pytest.mark.parametrize("slots,heads,kv_heads,head_dim,length,window,dtype", [
     # qwen2.5-3b decode: 8 slots x 2048 tokens, GQA 16/2
-    (16, 2, 128, 2048, None, "bfloat16"),
-    (16, 2, 128, 2048, None, "float32"),
+    (8, 16, 2, 128, 2048, None, "bfloat16"),
+    (8, 16, 2, 128, 2048, None, "float32"),
     # gemma3-12b local layer: a 1024-token ring, head_dim 256
-    (16, 8, 256, 1024, 1024, "bfloat16"),
+    (8, 16, 8, 256, 1024, 1024, "bfloat16"),
+    # the qwen2.5-3b serving pool: 32 slots x 2048 tokens in bf16
+    (32, 16, 2, 128, 2048, None, "bfloat16"),
 ])
-def test_attention_decode_compiles_for_v5e(one_chip, heads, kv_heads,
+def test_attention_decode_compiles_for_v5e(one_chip, slots, heads, kv_heads,
                                            head_dim, length, window, dtype):
-    b = 8
+    """The kernel on the merged-head pool [B, T, Hkv·Dh]: native, with
+    no VMEM temporaries beyond its own blocks, and the pool aliased so
+    that the append writes in place."""
+    b = slots
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
+    pool = sds((b, length, kv_heads * head_dim), dtype)
     compiled = jax.jit(functools.partial(
-        attention_decode_pallas, window=window, interpret=False)).lower(
+        attention_decode_pallas, window=window, interpret=False),
+        donate_argnums=(3, 4)).lower(
         sds((b, 1, heads, head_dim), jnp.bfloat16),
         sds((b, 1, kv_heads, head_dim), jnp.bfloat16),
         sds((b, 1, kv_heads, head_dim), jnp.bfloat16),
-        sds((b, length, kv_heads, head_dim), dtype),
-        sds((b, length, kv_heads, head_dim), dtype),
-        sds((b,), jnp.int32)).compile()
+        pool, pool, sds((b,), jnp.int32)).compile()
     assert _native(compiled)
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * b * length * kv_heads \
+        * head_dim * jnp.dtype(dtype).itemsize
 
 
 def test_data_parallel_fused_step_compiles_for_v5e(topo, monkeypatch):

@@ -31,12 +31,8 @@
 #      parity (kernel == oracle == jnp on f32/bf16 pools, ring
 #      wraparound) — the kernel tests re-run under REPRO_FORCE_REF=1
 #      so the jnp oracle dispatch is exercised too — then the serve
-#      bench quick run (BENCH_serve.json: >=1.5x tokens/sec vs
-#      sequential, prefill/decode phase split, kernel decode sweep,
-#      p50/p99 latency under Poisson load) + serve launcher smokes
-#      (jnp, and --use-kernel --trace-out with span validation), with
-#      an advisory gate vs baselines/BENCH_serve.json (us_per_call
-#      plus the deterministic modeled decode HBM bytes/token)
+#      launcher smokes (jnp, and --use-kernel --trace-out with span
+#      validation)
 #   5. multidevice: mesh-native numerics on 8 fabricated CPU devices
 #      (shard_map train-step parity, DP controller (D,K) retargeting,
 #      cross-mesh checkpoint round-trips; the GSPMD-parity subprocess
@@ -109,9 +105,6 @@ echo "== decode-kernel parity re-run (REPRO_FORCE_REF=1: jnp oracle dispatch) ==
 REPRO_FORCE_REF=1 python -m pytest -q tests/test_serving.py \
     -k "kernel or bf16_cache"
 
-echo "== serve bench quick run (experiments/bench/BENCH_serve.json) =="
-PYTHONPATH="src:.:$PYTHONPATH" python benchmarks/bench_serve.py --quick
-
 echo "== serve launcher smoke (continuous-batching engine, mid-flight admission) =="
 python -m repro.launch.serve --arch qwen2.5-3b --smoke --requests 6 \
     --prompt-len 12 --num-tokens 8 --slots 3
@@ -122,12 +115,6 @@ python -m repro.launch.serve --arch gemma3-12b --smoke --requests 4 \
     --use-kernel --trace-out experiments/bench/smoke_serve_trace.jsonl
 python tools/validate_metrics.py \
     experiments/bench/smoke_serve_trace.jsonl --min-trace-records 5
-
-echo "== serve bench regression gate (advisory) =="
-python tools/bench_compare.py benchmarks/baselines/BENCH_serve.json \
-    experiments/bench/BENCH_serve.json \
-    --metric us_per_call --metric modeled_hbm_bytes_per_token=0.01 || \
-    echo "bench_compare: ADVISORY failure (wall-clock noise is expected off dedicated hardware)"
 
 echo "== multidevice (8 fabricated CPU devices: shard_map parity, DP controller, sharded ckpts; GSPMD parity ran in tier 1) =="
 XLA_FLAGS=--xla_force_host_platform_device_count=8 \
