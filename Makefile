@@ -1,7 +1,7 @@
 .PHONY: check test fast bench bench-pipeline overlap obs serving \
 	serve-kernel smoke lint multidevice
 
-# tier-1 suite + REPRO_FORCE_REF=1 oracle re-run (both dispatch modes)
+# tier-1 suite + REPRO_FORCE_REF=1 re-run (Pallas kernels, then their oracles)
 # + e2e launcher smoke with gradient accumulation (K>1) + probe smoke
 # + lint + JSONL metrics-contract guard — mirrors the CI full job
 check:

@@ -2,17 +2,15 @@
 
 On this CPU container the kernels run in interpret mode, so wall-time is
 NOT indicative of TPU performance — the relevant numbers are (a) the
-ref-vs-kernel HBM-traffic model (derived column) and (b) the
-``pallas_calls`` launch counts, which are exact and backend-independent:
-the per-tensor path issues 2 launches per >=2-D leaf, the segmented
-substrate path exactly 2 per optimizer STEP regardless of leaf count —
-that launch collapse is the whole point of the flat substrate
+modeled HBM traffic (derived columns) and (b) the ``pallas_calls``
+launch counts, which are exact and backend-independent: the segmented
+substrate path issues exactly 2 per optimizer STEP regardless of leaf
+count — that launch collapse is the whole point of the flat substrate
 (``core/flatten.py`` + ``kernels/segmented_update.py``).
 
 Sections:
-  * per-tensor fused LARS vs jitted reference (traffic model + fusions)
   * optimizer-step dispatch sweep over model-registry param trees:
-    pure-jnp vs ``use_kernel="per_tensor"`` vs ``use_kernel="fused"``
+    pure-jnp vs ``use_kernel="fused"``
     under each precision policy (f32 / bf16_master), reporting us/step,
     pallas_call counts, resident substrate state bytes and the modeled
     per-step HBM traffic (``segmented_update.modeled_hbm_bytes``) —
@@ -39,7 +37,6 @@ from repro.core import apply_updates, build_optimizer
 from repro.core.layerwise import storage_dtype
 from repro.data.pipeline import stack_microbatches
 from repro.data.synthetic import lm_batch
-from repro.kernels import ref
 from repro.kernels.ops import count_pallas_calls
 from repro.kernels.segmented_update import modeled_hbm_bytes
 from repro.models import get_model
@@ -81,11 +78,8 @@ def bench_optimizer_dispatch(quick: bool = False) -> None:
             per_precision = {}   # precision -> modeled state bytes/step
             for uk, prec, label in (
                     (False, "f32", "jnp"),
-                    ("per_tensor", "f32", "per_tensor"),
                     ("fused", "f32", "fused"),
                     ("fused", "bf16_master", "fused_bf16_master")):
-                if opt_name != "wa-lars" and uk == "per_tensor":
-                    continue   # per-tensor kernel is heavy-ball LARS only
                 opt = build_optimizer(opt_name, total_steps=100,
                                       learning_rate=0.2, use_kernel=uk,
                                       precision=prec)
@@ -176,30 +170,6 @@ def main() -> None:
                     help="basename of the JSON written to "
                          "experiments/bench/")
     args = ap.parse_args()
-    rng = np.random.default_rng(0)
-    shape = (1024, 512)
-    w = jnp.asarray(rng.normal(size=shape), jnp.float32)
-    g = jnp.asarray(rng.normal(size=shape), jnp.float32)
-    m = jnp.asarray(rng.normal(size=shape), jnp.float32)
-    kw = dict(base_lr=0.1, eta=1e-3, weight_decay=5e-4, momentum_mu=0.9)
-
-    fused_ref = jax.jit(lambda w, g, m: ref.ref_lars_update(w, g, m, **kw))
-    us = time_fn(fused_ref, w, g, m)
-    nbytes = w.size * 4 * 5
-    record("kernels/lars_update_ref_jit", us,
-           traffic_model=f"{nbytes/1e6:.1f}MB/5-passes")
-
-    # HLO pass-count evidence for the fusion claim
-    txt = fused_ref.lower(w, g, m).compile().as_text()
-    n_fusion = txt.count(" fusion(")
-    record("kernels/lars_update_ref_fusions", 0.0, xla_fusions=n_fusion)
-
-    x = jnp.asarray(rng.normal(size=(256, 1024)), jnp.float32)
-    s = jnp.zeros((1024,))
-    rms_ref = jax.jit(lambda x, s: ref.ref_rmsnorm(x, s))
-    record("kernels/rmsnorm_ref_jit", time_fn(rms_ref, x, s),
-           traffic_model=f"{(x.size*4*2)/1e6:.1f}MB/2-passes")
-
     bench_optimizer_dispatch(quick=args.quick)
     bench_accumulation(quick=args.quick)
     path = write_json(args.json_name, suite="kernels",

@@ -19,12 +19,11 @@ per-device or per-microbatch size; the launcher passes its
 ``--global-batch`` here.
 
 ``use_kernel`` selects the layer-wise update's dispatch path
-(``repro.core.layerwise``): ``False`` = pure-jnp tree_map,
-``"per_tensor"`` = two Pallas calls per >=2-D leaf (heavy-ball LARS
-only), ``"fused"`` (alias ``True``) = the flat substrate — the whole
-tree updated by exactly two segmented Pallas calls per step, covering
-LARS (nesterov, trust_clip), both TVLARS momentum styles, and LAMB.
-Unsupported flag combinations raise at build time.
+(``repro.core.layerwise``): ``False`` = pure-jnp tree_map (the oracle),
+``"fused"`` (alias ``True``) = the flat substrate — the whole tree
+updated by exactly two segmented Pallas calls per step, covering LARS
+(nesterov, trust_clip), both TVLARS momentum styles, and LAMB. Any
+other value raises at build time.
 
 ``precision`` selects the fused substrate's mixed-precision policy:
 ``"f32"`` (default, bitwise-legacy), ``"bf16_master"`` (bf16 working
@@ -61,7 +60,7 @@ def build_optimizer(name: str, *, total_steps: int,
                     eta: float = 1e-3,
                     momentum: float = 0.9,
                     weight_decay: float = 5e-4,
-                    use_kernel=False,   # False | "per_tensor" | "fused"/True
+                    use_kernel=False,   # False | "fused"/True
                     precision: str = "f32",
                     momentum_style: str = "paper",
                     scaling_rule: str = "sqrt",

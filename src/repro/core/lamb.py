@@ -14,8 +14,8 @@ Built on the shared ``repro.core.layerwise`` core; with
 ``use_kernel="fused"`` (or ``True``) the Adam moments live as flat
 substrate buffers and the whole step — moments, segmented ‖w‖/‖r‖,
 trust scaling, apply — is two segmented Pallas calls
-(``kernels.segmented_update``, mode "lamb"). There is no per-tensor
-kernel for LAMB; ``use_kernel="per_tensor"`` raises.
+(``kernels.segmented_update``, mode "lamb"); ``use_kernel=False`` is
+the pure-jnp tree_map oracle.
 """
 from __future__ import annotations
 

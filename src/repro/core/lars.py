@@ -16,17 +16,14 @@ matching the cited reference implementations.
 The update itself is built by ``repro.core.layerwise`` (shared with
 TVLARS and LAMB). Dispatch story for the per-parameter hot-spot:
 
-  * ``use_kernel=False``        — pure-jnp tree_map (mesh-sharding
-                                  friendly; norms all-reduce per shard).
-  * ``use_kernel="per_tensor"`` — two Pallas calls per >=2-D leaf
-                                  (``kernels.lars_update``); heavy-ball
-                                  math only, so ``trust_clip`` raises.
-  * ``use_kernel="fused"``      — the flat substrate: the whole tree is
-                                  packed once and updated by two
-                                  segmented Pallas calls total
-                                  (``kernels.segmented_update``);
-                                  supports nesterov and ``trust_clip``.
-  * ``use_kernel=True``         — alias for ``"fused"``.
+  * ``use_kernel=False``   — pure-jnp tree_map, the oracle (mesh-sharding
+                             friendly; norms all-reduce per shard).
+  * ``use_kernel="fused"`` — the flat substrate: the whole tree is
+                             packed once and updated by two segmented
+                             Pallas calls total
+                             (``kernels.segmented_update``); supports
+                             nesterov and ``trust_clip``.
+  * ``use_kernel=True``    — alias for ``"fused"``.
 """
 from __future__ import annotations
 

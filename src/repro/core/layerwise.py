@@ -3,31 +3,26 @@
 ``lars.py``, ``tvlars.py`` and ``lamb.py`` used to carry three
 near-identical ``per_leaf``/tuple-unpacking ``tree_map`` bodies; they
 are now thin instantiations of :func:`layerwise_transform`, which owns
-labelling, state plumbing and the three dispatch paths:
+labelling, state plumbing and the two dispatch paths:
 
-  * ``use_kernel=False``        — pure-jnp ``tree_map`` over leaves
-                                  (sharding-friendly: per-leaf norms
-                                  lower to per-shard partials +
-                                  all-reduce under a mesh).
-  * ``use_kernel="per_tensor"`` — the original fused Pallas kernel, two
-                                  ``pallas_call``s PER >=2-D leaf
-                                  (heavy-ball LARS math only).
-  * ``use_kernel="fused"``      — the flat substrate: all leaves packed
-                                  into one lane-padded f32 buffer
-                                  (``core.flatten``), the whole step is
-                                  two segmented ``pallas_call``s
-                                  (``kernels.segmented_update``)
-                                  regardless of leaf count. Momentum /
-                                  Adam state is STORED flat, so only
-                                  params+grads pay pack traffic per
-                                  step. Covers every mode: heavy ball,
-                                  nesterov, trust_clip, TVLARS "paper"
-                                  momentum, and LAMB.
+  * ``use_kernel=False``   — pure-jnp ``tree_map`` over leaves, the
+                             oracle; also the GSPMD path, since per-leaf
+                             norms lower to per-shard partials +
+                             all-reduce where a Pallas call cannot be
+                             partitioned.
+  * ``use_kernel="fused"`` — the flat substrate: all leaves packed
+                             into one lane-padded f32 buffer
+                             (``core.flatten``), the whole step is two
+                             segmented ``pallas_call``s
+                             (``kernels.segmented_update``) regardless
+                             of leaf count. Momentum / Adam state is
+                             STORED flat, so only params+grads pay
+                             pack traffic per step. Covers every mode:
+                             heavy ball, nesterov, trust_clip, TVLARS
+                             "paper" momentum, and LAMB.
 
-``use_kernel=True`` is accepted as an alias for ``"fused"``.
-Unsupported combinations (e.g. ``"per_tensor"`` with ``trust_clip`` or
-TVLARS "paper" momentum) raise at build time instead of silently
-falling back — see ``_validate_use_kernel``.
+``use_kernel=True`` is accepted as an alias for ``"fused"`` and
+``None`` for ``False``; any other value raises at build time.
 
 Mixed precision (fused path only) — ``precision=``:
 
@@ -57,7 +52,7 @@ The elementwise math itself lives in ``repro.kernels.ref``
 (:func:`~repro.kernels.ref.direction` /
 :func:`~repro.kernels.ref.integrate` /
 :func:`~repro.kernels.ref.trust_scale_table`) and is shared verbatim by
-all three paths, so they agree by construction.
+both paths, so they agree by construction.
 """
 from __future__ import annotations
 
@@ -75,12 +70,7 @@ from repro.obs import scopes
 
 UseKernel = Union[bool, str]
 
-KERNEL_CHOICES = (False, "per_tensor", "fused")
-
 PRECISIONS = ("f32", "bf16_master", "bf16_master_sr")
-
-# which (mode, feature) combos the per-tensor kernel can express
-_PER_TENSOR_MODES = ("lars",)
 
 
 def storage_dtype(precision: str):
@@ -105,35 +95,14 @@ def _validate_precision(precision: str, use_kernel: UseKernel,
 
 
 def normalize_use_kernel(use_kernel: UseKernel) -> UseKernel:
-    """Map the public flag onto ``False | "per_tensor" | "fused"``.
-
-    ``True`` historically meant the per-tensor kernel; it now aliases
-    the strictly-more-capable fused path.
-    """
-    if use_kernel is True:
+    """Map the public flag onto ``False`` (tree path) or ``"fused"``."""
+    if use_kernel is True or use_kernel == "fused":
         return "fused"
     if use_kernel in (False, None):
         return False
-    if use_kernel not in ("per_tensor", "fused"):
-        raise ValueError(
-            f"use_kernel={use_kernel!r}; expected one of "
-            f"{(False, True) + KERNEL_CHOICES[1:]}")
-    return use_kernel
-
-
-def _validate_use_kernel(use_kernel: UseKernel, *, mode: str,
-                         trust_clip, optimizer: str) -> None:
-    if use_kernel != "per_tensor":
-        return
-    if mode not in _PER_TENSOR_MODES:
-        raise ValueError(
-            f"{optimizer}: use_kernel='per_tensor' only supports "
-            f"heavy-ball LARS math (got mode={mode!r}); use "
-            f"use_kernel='fused' which covers it")
-    if trust_clip is not None:
-        raise ValueError(
-            f"{optimizer}: use_kernel='per_tensor' does not support "
-            f"trust_clip; use use_kernel='fused'")
+    raise ValueError(
+        f"use_kernel={use_kernel!r}; expected one of "
+        f"(False, None, True, 'fused')")
 
 
 def layerwise_transform(base_lr_fn: Callable[[jnp.ndarray], jnp.ndarray], *,
@@ -157,7 +126,7 @@ def layerwise_transform(base_lr_fn: Callable[[jnp.ndarray], jnp.ndarray], *,
     ``mode``: "lars" (heavy ball, optional nesterov), "paper" (TVLARS
     Algorithm 1 parameter-space momentum) or "lamb" (Adam moments).
     ``state_cls(step, *bufs)`` is the optimizer's public state
-    NamedTuple; buffers are momentum trees (unfused/per-tensor) or flat
+    NamedTuple; buffers are momentum trees (tree path) or flat
     ``(rows, 128)`` substrate arrays (fused) at the ``precision``
     policy's storage dtype (f32, or bf16 under ``"bf16_master"`` /
     ``"bf16_master_sr"`` — fused only).
@@ -165,9 +134,8 @@ def layerwise_transform(base_lr_fn: Callable[[jnp.ndarray], jnp.ndarray], *,
     if mode not in ref.MODES:
         raise ValueError(f"unknown mode {mode!r}; one of {ref.MODES}")
     use_kernel = normalize_use_kernel(use_kernel)
-    _validate_use_kernel(use_kernel, mode=mode, trust_clip=trust_clip,
-                         optimizer=optimizer_name)
     _validate_precision(precision, use_kernel, optimizer_name)
+    fused = use_kernel == "fused"
     sdtype = storage_dtype(precision)
     stochastic = precision.endswith("_sr")
     n_bufs = 2 if mode == "lamb" else 1
@@ -190,7 +158,7 @@ def layerwise_transform(base_lr_fn: Callable[[jnp.ndarray], jnp.ndarray], *,
 
     def init(params):
         bufs = _init_buffer_trees(params)
-        if use_kernel == "fused":
+        if fused:
             spec = flatten.build_spec(params, _labels(params),
                                       dtype=sdtype)
             bufs = tuple(flatten.pack_tree(b, spec) for b in bufs)
@@ -235,7 +203,7 @@ def layerwise_transform(base_lr_fn: Callable[[jnp.ndarray], jnp.ndarray], *,
             updates = flatten.unpack_tree(delta2d, spec)
         return updates, state_cls(state.step + 1, *new_bufs)
 
-    # ---- tree paths: per-leaf jnp math, optional per-tensor kernel ----
+    # ---- tree path: per-leaf jnp math, the oracle ----
 
     def _update_tree(grads, state, params):
         lab = _labels(params)
@@ -245,28 +213,12 @@ def layerwise_transform(base_lr_fn: Callable[[jnp.ndarray], jnp.ndarray], *,
         # the same segment order the fused substrate packs, so the two
         # paths' telemetry streams are name-compatible
         rows: list = []
-        if use_kernel == "per_tensor":
-            from repro.kernels import ops as kops
 
         def per_leaf(g, w, *bufs_and_tag):
             bufs, tag = bufs_and_tag[:-1], bufs_and_tag[-1]
             g32 = g.astype(jnp.float32)
             w32 = w.astype(jnp.float32)
             adapt = tag == labels_lib.ADAPT
-            if (use_kernel == "per_tensor" and adapt
-                    and w.ndim >= 1 and w.size >= 8):
-                new_m, delta = kops.lars_update(
-                    w32, g32, bufs[0], base_lr=base_lr, eta=eta,
-                    weight_decay=weight_decay, momentum_mu=momentum,
-                    eps=eps, nesterov=nesterov)
-                if telemetry:
-                    # per-tensor kernel is "lars"-only: bvec == g
-                    rows.append(ref.trust_ratio(
-                        jnp.sum(jnp.square(w32)), jnp.sum(jnp.square(g32)),
-                        jnp.asarray(adapt), mode=mode, eta=eta,
-                        weight_decay=weight_decay, eps=eps,
-                        trust_clip=trust_clip))
-                return (new_m, delta)
             d, bufs2 = ref.direction(mode, w32, g32, bufs, b1=b1, b2=b2,
                                      bc1=bc1, bc2=bc2, eps=eps)
             # same table math as the fused host pass, on a 1-segment
@@ -306,7 +258,7 @@ def layerwise_transform(base_lr_fn: Callable[[jnp.ndarray], jnp.ndarray], *,
     def update(grads, state, params=None):
         if params is None:
             raise ValueError(f"{optimizer_name} requires params")
-        if use_kernel == "fused":
+        if fused:
             return _update_fused(grads, state, params)
         return _update_tree(grads, state, params)
 

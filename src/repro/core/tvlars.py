@@ -19,16 +19,16 @@ Momentum (Algorithm 1 lines 7–8, the paper's parameter-space heavy ball):
 ``momentum_style="paper"`` implements exactly that (the momentum buffer
 stores the previous *proposed parameters*; m_0 := w_0 so step 0 is a
 plain scaled step). ``momentum_style="lars"`` uses the conventional
-LARS buffer (m ← μm + γ(g+wd·w); w ← w − m). Both are tested; see
-DESIGN.md §1 for the Algorithm-1 typo note.
+LARS buffer (m ← μm + γ(g+wd·w); w ← w − m). Both are tested. The two
+equations above are this module's reading of Algorithm 1, whose printed
+lines 7–8 carry a typo.
 
 TVLARS uses NO external LR scheduler (Appendix B) — φ_t is the schedule.
 
 Kernel dispatch (shared ``repro.core.layerwise`` core): the fused flat
 substrate (``use_kernel="fused"``/``True``) covers BOTH momentum styles
-in two segmented Pallas calls per step; ``"per_tensor"`` only expresses
-the conventional heavy-ball buffer and raises for
-``momentum_style="paper"`` instead of silently falling back.
+in two segmented Pallas calls per step; ``use_kernel=False`` runs the
+same math as a pure-jnp tree_map, the oracle.
 """
 from __future__ import annotations
 

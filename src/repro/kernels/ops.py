@@ -5,15 +5,17 @@ On TPU the kernels compile natively; on CPU (this container) they run in
 correctness tests exercise the real kernel logic. ``REPRO_FORCE_REF=1``
 falls back to the pure-jnp oracles (useful for bisecting kernel bugs).
 
-Two kernel families back the layer-wise optimizers:
+Two kernels:
 
-  * ``lars_update``      — per-tensor fused step (two ``pallas_call``s
-                           per leaf); heavy-ball LARS only.
-  * ``segmented_update`` — whole-tree fused step on the flat substrate
-                           (two ``pallas_call``s per STEP, any leaf
-                           count); covers LARS (incl. nesterov +
-                           trust_clip), TVLARS both momentum styles,
-                           and LAMB. See ``repro.core.layerwise``.
+  * ``segmented_update``       — the layer-wise optimizers' whole-tree
+                                 step on the flat substrate (two
+                                 ``pallas_call``s per STEP, any leaf
+                                 count); covers LARS (incl. nesterov +
+                                 trust_clip), TVLARS both momentum
+                                 styles, and LAMB. See
+                                 ``repro.core.layerwise``.
+  * ``attention_decode_fused`` — the serving decode step's attention
+                                 (one ``pallas_call`` per layer).
 """
 from __future__ import annotations
 
@@ -23,8 +25,6 @@ import jax
 
 from repro.kernels import ref
 from repro.kernels.attention_decode import attention_decode_pallas
-from repro.kernels.lars_update import lars_update_pallas
-from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.segmented_update import segmented_update_pallas
 
 
@@ -34,19 +34,6 @@ def _interpret() -> bool:
 
 def _force_ref() -> bool:
     return os.environ.get("REPRO_FORCE_REF", "0") == "1"
-
-
-def lars_update(w, g, m, *, base_lr, eta, weight_decay, momentum_mu,
-                eps: float = 1e-9, nesterov: bool = False):
-    """Fused LARS trust-ratio + momentum step -> (new_momentum, delta)."""
-    if _force_ref():
-        return ref.ref_lars_update(
-            w, g, m, base_lr=base_lr, eta=eta, weight_decay=weight_decay,
-            momentum_mu=momentum_mu, eps=eps, nesterov=nesterov)
-    return lars_update_pallas(
-        w, g, m, base_lr=base_lr, eta=eta, weight_decay=weight_decay,
-        momentum_mu=momentum_mu, eps=eps, nesterov=nesterov,
-        interpret=_interpret())
 
 
 def segmented_update(w2d, g2d, bufs, **kw):
@@ -66,13 +53,6 @@ def segmented_update(w2d, g2d, bufs, **kw):
         return ref.ref_segmented_update(w2d, g2d, bufs, **kw)
     return segmented_update_pallas(w2d, g2d, bufs, interpret=_interpret(),
                                    **kw)
-
-
-def rmsnorm(x, weight, *, eps: float = 1e-6):
-    """Fused RMSNorm (gemma convention: scale = 1 + weight)."""
-    if _force_ref():
-        return ref.ref_rmsnorm(x, weight, eps=eps)
-    return rmsnorm_pallas(x, weight, eps=eps, interpret=_interpret())
 
 
 def attention_decode_fused(q, new_k, new_v, k_cache, v_cache, pos, *,

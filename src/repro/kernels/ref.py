@@ -5,10 +5,10 @@ tests sweep shapes/dtypes and assert_allclose kernel-vs-ref.
 
 This module also hosts the *shared* layer-wise update math
 (:func:`direction`, :func:`integrate`, :func:`trust_scale_table`) used
-by all three dispatch paths — the pure tree_map path in
-``repro.core.layerwise``, the per-tensor Pallas kernel, and the
-segmented (fused multi-tensor) kernel — so the paths agree by
-construction and parity tests only have to catch kernel plumbing bugs.
+by both dispatch paths — the pure tree_map path in
+``repro.core.layerwise`` and the segmented (fused multi-tensor)
+kernel — so the paths agree by construction and parity tests only
+have to catch kernel plumbing bugs.
 
 The unified update for every optimizer in the family is
 
@@ -203,31 +203,6 @@ def trust_scale_table(w2, b2, adapt_mask, base_lr, *, mode: str,
 
 
 # ---------------------------------------------------------------------------
-# per-tensor oracle (matches kernels/lars_update.py)
-# ---------------------------------------------------------------------------
-
-def ref_lars_update(w: jnp.ndarray, g: jnp.ndarray, m: jnp.ndarray, *,
-                    base_lr, eta: float, weight_decay: float,
-                    momentum_mu: float, eps: float = 1e-9,
-                    nesterov: bool = False):
-    """LARS trust-ratio + momentum + delta (matches core/lars.py ADAPT path).
-
-    Returns (new_momentum, delta) where new params = w + delta.
-    """
-    w32 = w.astype(jnp.float32)
-    g32 = g.astype(jnp.float32)
-    w_norm = jnp.sqrt(jnp.sum(jnp.square(w32)))
-    g_norm = jnp.sqrt(jnp.sum(jnp.square(g32)))
-    denom = g_norm + weight_decay * w_norm + eps
-    ratio = jnp.where((w_norm > 0.0) & (g_norm > 0.0),
-                      eta * w_norm / denom, 1.0)
-    scaled = base_lr * ratio * (g32 + weight_decay * w32)
-    new_m = momentum_mu * m + scaled
-    step_dir = scaled + momentum_mu * new_m if nesterov else new_m
-    return new_m, -step_dir
-
-
-# ---------------------------------------------------------------------------
 # segmented (fused multi-tensor) oracle — matches kernels/segmented_update.py
 # ---------------------------------------------------------------------------
 
@@ -305,15 +280,6 @@ def ref_segmented_update(w2d, g2d, bufs, *, seg_ids, adapt_mask, base_lr,
         telem = {"w_norm": wn, "g_norm": bn, "trust_ratio": ratio}
         return new_bufs, delta, telem
     return new_bufs, delta
-
-
-def ref_rmsnorm(x: jnp.ndarray, weight: jnp.ndarray,
-                eps: float = 1e-6) -> jnp.ndarray:
-    """RMSNorm: x / rms(x) * (1 + weight)   (gemma/llama convention)."""
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    y = x32 / jnp.sqrt(var + eps)
-    return (y * (1.0 + weight.astype(jnp.float32))).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,9 @@
 """Segmented multi-tensor optimizer-update Pallas TPU kernels.
 
-The per-tensor kernel (``lars_update.py``) issues two ``pallas_call``s
-PER LEAF — launch-bound and tile-underfilled on models with hundreds of
-small tensors. These kernels operate on the flat substrate from
-``repro.core.flatten`` instead, so one optimizer step is exactly two
-``pallas_call``s TOTAL, regardless of leaf count:
+A kernel per leaf would be launch-bound and tile-underfilled on models
+with hundreds of small tensors. These kernels operate on the flat
+substrate from ``repro.core.flatten`` instead, so one optimizer step is
+exactly two ``pallas_call``s TOTAL, regardless of leaf count:
 
   pass 1  ``_seg_norm_*``   — one sweep over the (num_rows, 128) buffer
                               accumulating per-SEGMENT Σw², Σb² into a

@@ -112,7 +112,7 @@ def main() -> None:
                          "are accumulated (default: --global-batch)")
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--use-kernel", default="off",
-                    choices=("off", "per_tensor", "fused"),
+                    choices=("off", "fused"),
                     help="optimizer dispatch path: 'fused' runs the "
                          "whole update as two segmented pallas_calls "
                          "(see repro.core.layerwise)")

@@ -25,7 +25,7 @@ NEG_INF = -2.0e38  # f32-safe mask value
 # ---------------------------------------------------------------------------
 # activation sharding anchor.
 #
-# Two measured GSPMD pathologies this fixes (see EXPERIMENTS.md §Perf):
+# Two measured GSPMD pathologies this fixes:
 #  1. the token-embedding gather (data-sharded indices into a
 #     vocab-sharded table) REPLICATES its output over the data axes,
 #     silently un-sharding the batch for the entire network
@@ -207,8 +207,6 @@ def _qkv(params: dict, x: jnp.ndarray, kv_src: jnp.ndarray, cfg: ModelConfig):
 # backward buffers were ~12 GiB/dev on qwen2-72b train_4k). The chunk
 # body is checkpointed so the backward holds ONE chunk's f32 scores.
 Q_CHUNK = 512
-# see the refuted-hypothesis note at the kv_span computation below
-WINDOWED_KV_SLICING = False
 
 
 def gqa_scores_apply(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -226,7 +224,6 @@ def gqa_scores_apply(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """
     b, s, h, dh = q.shape
     hkv = k.shape[2]
-    t = k.shape[1]
 
     if s == 1:
         # decode path: GROUPED einsum, never broadcasting K/V to full
@@ -264,10 +261,8 @@ def gqa_scores_apply(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                              (b, v.shape[1], hkv, rep, dh)
                              ).reshape(b, v.shape[1], h, dh)
 
-    def full(qq, mm, q_offset, kk=None, vv=None, k_start=0):
-        kk = k if kk is None else kk
-        vv = v if vv is None else vv
-        scores = jnp.einsum("bshd,bthd->bhst", qq, kk).astype(jnp.float32)
+    def full(qq, mm, q_offset):
+        scores = jnp.einsum("bshd,bthd->bhst", qq, k).astype(jnp.float32)
         scores = scores / math.sqrt(dh)
         if isinstance(mm, tuple):
             # lazy causal/window mask — never materialise a [S,T] f32
@@ -275,7 +270,7 @@ def gqa_scores_apply(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             # rows is built inline and fused into the masked softmax.
             _, window = mm
             qpos = q_offset + jnp.arange(qq.shape[1])[:, None]
-            kpos = k_start + jnp.arange(kk.shape[1])[None, :]
+            kpos = 0 + jnp.arange(k.shape[1])[None, :]
             ok = kpos <= qpos
             if window is not None:
                 ok = ok & (kpos > qpos - window)
@@ -283,7 +278,7 @@ def gqa_scores_apply(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         elif mm is not None:
             scores = scores + mm
         probs = jax.nn.softmax(scores, axis=-1).astype(qq.dtype)
-        return jnp.einsum("bhst,bthd->bshd", probs, vv)
+        return jnp.einsum("bhst,bthd->bshd", probs, v)
 
     if s <= Q_CHUNK or s % Q_CHUNK != 0:
         return full(q, mask, 0)
@@ -292,17 +287,10 @@ def gqa_scores_apply(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     nblk = s // Q_CHUNK
     qb = q.reshape(b, nblk, Q_CHUNK, h, dh)
 
-    # sliding-window layers see only (window + chunk) keys per q-chunk,
-    # so slicing K/V instead of masking all T keys looks like a 21x win
-    # (gemma3 local at 32k: 32768 -> 1536 keys/chunk). MEASURED REFUTED
-    # under SPMD: dynamic_slice with a traced offset on the sharded K/V
-    # forces GSPMD to all-gather them per layer (gemma3 train_4k
-    # collective 20.7 -> 70.8 s/step, memory 17.2 -> 20.5 GiB). Kept
-    # behind a flag (useful on unsharded/single-host runs); the sharded
-    # fix would be a shard_map halo exchange (EXPERIMENTS.md §Perf c.2).
-    win = mask[1] if isinstance(mask, tuple) else None
-    kv_span = Q_CHUNK + win if (WINDOWED_KV_SLICING and win is not None
-                                and t > Q_CHUNK + win) else None
+    # every chunk masks over all T keys, sliding-window layers included:
+    # slicing a window's K/V with a traced offset makes GSPMD all-gather
+    # the sharded K/V per layer (measured on gemma3 train_4k: collective
+    # 20.7 -> 70.8 s/step, memory 17.2 -> 20.5 GiB)
 
     @jax.checkpoint
     def chunk(qi, i):
@@ -311,11 +299,6 @@ def gqa_scores_apply(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         if mask is not None and not isinstance(mask, tuple) \
                 and mask.shape[2] > 1:
             mi = jax.lax.dynamic_slice_in_dim(mask, off, Q_CHUNK, axis=2)
-        if kv_span is not None:
-            start = jnp.clip(off + Q_CHUNK - kv_span, 0, t - kv_span)
-            kk = jax.lax.dynamic_slice_in_dim(k, start, kv_span, axis=1)
-            vv = jax.lax.dynamic_slice_in_dim(v, start, kv_span, axis=1)
-            return full(qi, mi, off, kk, vv, start)
         return full(qi, mi, off)
 
     def body(_, xs):

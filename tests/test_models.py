@@ -218,23 +218,20 @@ def test_cnn_inits_and_forward():
         assert np.isfinite(np.asarray(logits)).all()
 
 
-def test_windowed_kv_slicing_flag_exact():
-    """The (default-off) windowed KV slicing path is exact when enabled;
-    it is off by default because dynamic_slice on sharded K/V makes
-    GSPMD all-gather them (EXPERIMENTS.md §Perf c, refuted hypothesis)."""
+def test_windowed_chunked_scores_match_unchunked():
+    """The query-chunk scan on a sliding-window layer equals one pass."""
     from repro.models import layers as L
-    import numpy as np
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(2, 64, 4, 8)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(2, 64, 2, 8)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(2, 64, 2, 8)), jnp.float32)
-    ref = L.gqa_scores_apply(q, k, v, ("causal", 8))
-    old_chunk, old_flag = L.Q_CHUNK, L.WINDOWED_KV_SLICING
+    old = L.Q_CHUNK
     try:
-        L.Q_CHUNK, L.WINDOWED_KV_SLICING = 8, True
+        L.Q_CHUNK = 64
+        ref = L.gqa_scores_apply(q, k, v, ("causal", 8))
+        L.Q_CHUNK = 8
         out = L.gqa_scores_apply(q, k, v, ("causal", 8))
     finally:
-        L.Q_CHUNK, L.WINDOWED_KV_SLICING = old_chunk, old_flag
+        L.Q_CHUNK = old
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                rtol=1e-5, atol=1e-6)
-    assert L.WINDOWED_KV_SLICING is False   # default stays off

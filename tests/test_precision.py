@@ -191,9 +191,6 @@ def test_validate_precision_raises():
     with pytest.raises(ValueError, match="fused"):
         lars(schedules.constant(0.1), use_kernel=False,
              precision="bf16_master")
-    with pytest.raises(ValueError, match="fused"):
-        lars(schedules.constant(0.1), use_kernel="per_tensor",
-             precision="bf16_master")
     with pytest.raises(ValueError, match="precision"):
         lars(schedules.constant(0.1), use_kernel="fused",
              precision="fp8")
